@@ -1,5 +1,6 @@
-"""Shared benchmark utilities: the TPU v5e hardware model used by the
-scaling/roofline projections, the repo's CSV line format, and the
+"""Shared benchmark utilities: the per-device peaks table (and the v5e
+hardware model the scaling/roofline projections target), the repo's CSV
+line format, and the
 benchmark *trajectory* — an append-only JSONL history of runs.
 
 Timing lives in ``repro.api.timing`` (warm-up + ``block_until_ready``; the
@@ -18,10 +19,31 @@ import json
 import subprocess
 import time
 
-# TPU v5e constants (per chip) — the dry-run's target hardware
-PEAK_FLOPS = 197e12          # bf16
-HBM_BW = 819e9               # bytes/s
-ICI_BW = 50e9                # bytes/s/link
+#: published per-chip peaks, keyed by ``jax.devices()[0].device_kind``.
+#: TPU v5e: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB
+#: of HBM at 819 GB/s, 1,600 Gbit/s of ICI per chip over 4 links.
+PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bytes": 16e9,
+                    "hbm_bytes_per_s": 819e9, "ici_bytes_per_s_link": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """The peaks of ``device_kind``; a kind not in ``PEAKS`` is an error,
+    never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}"
+                       f"; add them to benchmarks.common.PEAKS with their "
+                       f"source") from None
+
+
+#: the chip the scaling model and the dry-run roofline project onto
+MODEL_DEVICE = "TPU v5 lite"
+PEAK_FLOPS = peaks(MODEL_DEVICE)["flops_bf16"]
+HBM_BW = peaks(MODEL_DEVICE)["hbm_bytes_per_s"]
+ICI_BW = peaks(MODEL_DEVICE)["ici_bytes_per_s_link"]
 ALLREDUCE_LAT = 5e-6         # base latency per hop-stage (model parameter)
 
 

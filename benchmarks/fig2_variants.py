@@ -67,9 +67,12 @@ print(json.dumps(out))
 """
 
 
-def _run_trace(view: str) -> dict | None:
+def _run_trace(view: str) -> dict:
     env = dict(os.environ)
     env["TRACE_VIEW"] = view
+    # an analysis on host devices: the child must never reach for the
+    # accelerator the parent already holds
+    env["JAX_PLATFORMS"] = "cpu"
     if view == "algo":   # algorithm-level dependence structure, unfused
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                             " --xla_disable_hlo_passes="
@@ -79,8 +82,8 @@ def _run_trace(view: str) -> dict | None:
         timeout=560, env=env,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     if proc.returncode != 0:
-        csv(f"fig1_trace_{view}", 0.0, f"subprocess_failed:{proc.stderr[-200:]}")
-        return None
+        raise RuntimeError(f"fig1 trace ({view}) subprocess failed:\n"
+                           f"{proc.stderr[-2000:]}")
     return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
@@ -115,8 +118,7 @@ def main() -> None:
     # structural barrier trace (Fig. 1 analogue): one subprocess per view
     slacks: dict = {}
     for view in ("algo", "fused"):
-        part = _run_trace(view)
-        for m, views in (part or {}).items():
+        for m, views in _run_trace(view).items():
             slacks.setdefault(m, {}).update(views)
     vec = 32 ** 3 * 4 // 8
     for m, views in slacks.items():
@@ -127,4 +129,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -64,6 +64,8 @@ def main(precond: bool = False) -> None:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--precond", action="store_true",

@@ -35,7 +35,9 @@ print(f"distributed: iters={int(res.iters)} res={float(res.res_norm):.2e}  "
 
 if __name__ == "__main__":
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    subprocess.run([sys.executable, "-c", _SCRIPT], cwd=root, check=True)
+    # the demo's mesh is 8 host devices: the child never touches a chip
+    subprocess.run([sys.executable, "-c", _SCRIPT], cwd=root, check=True,
+                   env={**os.environ, "JAX_PLATFORMS": "cpu"})
 
     sys.path.insert(0, os.path.join(root))
     sys.path.insert(0, os.path.join(root, "src"))

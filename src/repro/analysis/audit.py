@@ -283,6 +283,8 @@ def run_measurements(methods: list[str] | None = None, *,
                           f"{N_DEVICES}").strip()
     env["PYTHONPATH"] = (os.path.join(src, "src") + os.pathsep
                          + env.get("PYTHONPATH", ""))
+    # the audit compiles on host devices: never touch an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, "-c",
          "from repro.analysis.audit import worker_main; worker_main()"],

@@ -14,7 +14,7 @@ Resolution rules (documented in docs/API.md):
   3. ``layout="auto"`` picks local on one device, else the paper-faithful
      1-D z decomposition over all devices.
   4. ``layout="1d" | "2d" | "3d"`` build the corresponding mesh over all
-     devices (1-D ``cells`` / data×model / pod×data×model).
+     devices (1-D ``cells`` / near-square data×model / pod×data×model).
 
 The kernel choice is orthogonal: ``options.pallas`` swaps the local stencil
 SpMV for the Pallas kernel in either world (``options.matvec_padded`` wins
@@ -33,7 +33,7 @@ from repro.api.options import SolverOptions
 from repro.core.compat import make_mesh
 from repro.core.distributed import GridLayout, make_layout
 from repro.core.operators import Stencil
-from repro.launch.mesh import make_mesh_for_devices, make_solver_mesh
+from repro.launch.mesh import make_solver_mesh, make_solver_mesh_2d
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,7 +85,7 @@ def resolve_backend(options: SolverOptions, *, mesh: Mesh | None = None,
     if layout in ("auto", "1d"):
         mesh = make_solver_mesh(n)
     elif layout == "2d":
-        mesh = make_mesh_for_devices(n)
+        mesh = make_solver_mesh_2d(n)
     else:  # "3d"
         mesh = _mesh_3d(n)
     return Backend(kind="shard_map", mesh=mesh,

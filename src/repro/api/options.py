@@ -49,12 +49,14 @@ class SolverOptions:
                   silently accepted.
     layout:       device decomposition: ``"auto"`` (local on 1 device, else
                   the paper-faithful 1-D z split), ``"local"``, ``"1d"``,
-                  ``"2d"`` (data×model mesh), ``"3d"`` (pod×data×model).
-    pallas:       back the local stencil SpMV with the Pallas kernel.
+                  ``"2d"`` (near-square data×model mesh), ``"3d"``
+                  (pod×data×model).
+    pallas:       back the local stencil SpMV with the Pallas kernel
+                  (float32/bfloat16 on a TPU: a float64 problem raises).
                   ``None`` = "auto": ``kernels.autotune`` decides per
                   (stencil, grid, dtype, device_kind) — the persisted tune
-                  cache when one exists, else the default table (TPU and
-                  grid volume >= 24³).  Resolved to a concrete bool at
+                  cache when one exists, else the default table (TPU, not
+                  float64, and grid volume >= 24³).  Resolved to a concrete bool at
                   session construction.
     norm_ref:     residual normalisation; ``1.0`` = the paper's absolute
                   HPCCG criterion, ``None`` = relative to ``||b||``.

@@ -35,7 +35,6 @@ from repro.api.backend import (Backend, resolve_backend, resolve_halo_mode,
 from repro.api.options import SolverOptions
 from repro.api.registry import SolverSpec, fallback_chain, get_solver
 from repro.api.timing import timed_result
-from repro.core.compat import shard_map
 from repro.core.distributed import DistributedOp, solve_shardmap, solve_step_shardmap
 from repro.core.methods import (STATUS_BREAKDOWN, STATUS_DIVERGED,
                                 STATUS_STAGNATED, SolveBreakdown, status_name)
@@ -94,6 +93,10 @@ class SolverSession:
             dec = autotune.resolve(problem.stencil.name, problem.shape,
                                    problem.dtype)
             self.options = self.options.replace(pallas=dec.use_pallas)
+        if self.options.pallas or (self.options.precond_params or {}).get(
+                "use_pallas"):
+            from repro.kernels import ops
+            ops.check_dtype(problem.dtype)
         # solve-lifecycle spans (repro.obs): resolve -> precond.setup ->
         # compile (in _executable) -> execute (in solve/solve_batched)
         with obs.span("resolve", method=method, layout=self.options.layout,
@@ -475,7 +478,7 @@ class SolverSession:
                                 **self._solver_kwargs(op))
 
         bspec = P(None, *layout.dim_axes)
-        fn = shard_map(
+        fn = jax.shard_map(
             jax.vmap(local_solve),
             mesh=self.backend.mesh,
             in_specs=(bspec, bspec),

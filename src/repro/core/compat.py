@@ -1,77 +1,19 @@
-"""Version compatibility for the JAX surface this repo uses.
+"""Mesh construction with the axis types this repo's shard_map code expects.
 
-The code targets the modern spellings (``jax.shard_map``, ``jax.make_mesh``
-with ``axis_types``); this shim lets the same source run on older jaxlibs
-(0.4.x) where shard_map still lives in ``jax.experimental`` and meshes have
-no axis types.  Everything mesh/shard_map-shaped goes through here.
+``jax.make_mesh`` defaults to ``Explicit`` axes; every mesh here is built
+with ``Auto`` axes, which is what the ``jax.shard_map`` bodies (manual
+collectives over named axes) are written against.
 """
 
 from __future__ import annotations
 
-import inspect
-from typing import Callable, Sequence
+from typing import Sequence
 
 import jax
-
-_HAS_AXIS_TYPES = "axis_types" in inspect.signature(jax.make_mesh).parameters
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    if _HAS_AXIS_TYPES:
-        from jax.sharding import AxisType
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                             axis_types=(AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
-
-
-def pvary(x, axis_names):
-    """``lax.pvary`` where it exists; identity on older jax (the varying-axes
-    annotation only matters for shard_map's rep checking, which the old-jax
-    shim disables via ``check_rep=False``)."""
-    from jax import lax
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, axis_names)
-    return x
-
-
-def _ensure_optimization_barrier_batchable() -> None:
-    """Older jax has no batching rule for ``lax.optimization_barrier``; the
-    barrier is batch-transparent, so register the identity rule (needed to
-    vmap bicgstab_b1 for the batched multi-RHS path).  Checked against the
-    batcher registry directly — no traced probe, so importing this module
-    never initialises the device backend."""
-    try:
-        from jax._src.lax.lax import optimization_barrier_p
-        from jax.interpreters import batching
-    except ImportError:      # newer jax: internals moved AND rule exists
-        return
-    if optimization_barrier_p not in batching.primitive_batchers:
-        def _batcher(args, dims):
-            return optimization_barrier_p.bind(*args), dims
-
-        batching.primitive_batchers[optimization_barrier_p] = _batcher
-
-
-_ensure_optimization_barrier_batchable()
-
-
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict (older jax returns a
-    one-element list of per-program dicts)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
-if hasattr(jax, "shard_map"):
-    def shard_map(f: Callable, *, mesh, in_specs, out_specs) -> Callable:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-else:  # jax < 0.6: experimental module; check_rep chokes on psum-in-loop
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f: Callable, *, mesh, in_specs, out_specs) -> Callable:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+    """``jax.make_mesh`` with ``Auto`` axis types."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names))

@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.compat import shard_map
 from repro.core.methods import Ops, get_method, run_method
 from repro.core.operators import Stencil, interior_matvec, shell_assemble
 from repro.core.problems import HPCGProblem
@@ -359,13 +358,16 @@ def solve_shardmap(
                           guard_spec=guard_spec, refresh_every=refresh_every)
 
     spec = layout.spec()
-    fn = shard_map(
+    fn = jax.shard_map(
         local_solve,
         mesh=mesh,
         in_specs=(spec, spec),
         out_specs=SolveResult(x=spec, iters=P(), res_norm=P(), history=P(),
                               telemetry=P() if telemetry else None,
                               status=P()),
+        # Pallas's interpreter (the kernels off a TPU) does not carry
+        # varying-axes types through its grid loop
+        check_vma=not pallas_fused,
     )
     return fn, layout
 
@@ -428,10 +430,11 @@ def solve_step_shardmap(
 
     spec = layout.spec()
     nvec, nscal = len(mdef.vectors), len(mdef.scalars)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(spec,) * (1 + nvec) + (P(),) * nscal,
         out_specs=(spec,) * nvec + (P(),) * nscal,
+        check_vma=not pallas_fused,   # as in solve_shardmap
     )
     return fn, layout

@@ -11,6 +11,7 @@ is ``1e-5`` (paper §4.1).
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -41,14 +42,21 @@ class HPCGProblem:
 
     def b(self) -> jax.Array:
         """RHS for x* = 1: b = A @ ones (zero in the interior for HPCG-27)."""
-        ones = jnp.ones(self.shape, self.dtype)
-        return self.stencil.matvec(ones)
+        return _rhs(self.stencil, self.shape, self.dtype)
 
     def x0(self) -> jax.Array:
         return jnp.zeros(self.shape, self.dtype)
 
     def x_true(self) -> jax.Array:
         return jnp.ones(self.shape, self.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _rhs(stencil: Stencil, shape, dtype) -> jax.Array:
+    # jitted so XLA fuses the stencil's 27 terms: run op by op, each term is
+    # a grid-sized buffer, and at 512³ float32 they reached 14.6 GB of peak
+    # bytes in use on a 16.9 GB TPU v5e
+    return stencil.matvec(jnp.ones(shape, dtype))
 
 
 def make_problem(

@@ -2,7 +2,7 @@
 # BlockSpec VMEM tiling), each with a jit'd wrapper in ops.py and a pure-jnp
 # oracle in ref.py (validated via interpret=True on CPU):
 #
-#   stencil_spmv     — 7/27-pt stencil SpMV, overlapping-window z-slabs,
+#   stencil_spmv     — 7/27-pt stencil SpMV, overlapping-window x-slabs,
 #                      optional fused (A·x)·x partial (the paper's SpMV)
 #   fused_axpby      — the paper's ad hoc z := a·x + b·y + c·z (+ fused dot)
 #   cg_fused_update  — Alg.1 Tk1&2 in one VMEM pass (Ap, p updates + dot)

@@ -17,8 +17,10 @@ miss falls back to the static default table below, so nothing ever
 
   default table
   -------------
-  use_pallas :  backend == "tpu"  AND  nx·ny·nz >= MIN_PALLAS_VOLUME (24³)
-  bz         :  8   (shrunk per-shape by ``_pick_bz`` as always)
+  use_pallas :  backend == "tpu"  AND  dtype is not float64
+                AND  nx·ny·nz >= MIN_PALLAS_VOLUME (24³)
+  bz         :  8   x-planes per slab (shrunk per shape by
+                ``stencil_spmv.slab_depth`` to divide nx and fit VMEM)
   br         :  None (each kernel's own VMEM-budgeted default)
 
 Cache file: ``$REPRO_AUTOTUNE_CACHE`` or ``~/.cache/repro/autotune.json``.
@@ -111,11 +113,14 @@ def save_cache(table: dict, path: Path | None = None) -> Path:
     return path
 
 
-def default_decision(grid, *, backend: str | None = None) -> TuneDecision:
-    """The documented static fallback (no cache entry, no tuning run)."""
+def default_decision(grid, *, backend: str | None = None,
+                     dtype=jnp.float32) -> TuneDecision:
+    """The documented static fallback (no cache entry, no tuning run).
+    f64 stays on XLA: the TPU's kernel compiler lowers no 64-bit floats."""
     backend = jax.default_backend() if backend is None else backend
     nx, ny, nz = grid
-    on = backend == "tpu" and nx * ny * nz >= MIN_PALLAS_VOLUME
+    on = (backend == "tpu" and jnp.dtype(dtype) != jnp.float64
+          and nx * ny * nz >= MIN_PALLAS_VOLUME)
     return TuneDecision(use_pallas=on)
 
 
@@ -124,7 +129,7 @@ def resolve(stencil: str, grid, dtype, *,
     """Cache lookup with default-table fallback (the PallasOp/session read)."""
     entry = load_cache(path).get(tune_key(stencil, grid, dtype))
     if entry is None:
-        return default_decision(grid)
+        return default_decision(grid, dtype=dtype)
     return TuneDecision(use_pallas=bool(entry["use_pallas"]),
                         bz=int(entry["bz"]),
                         br=None if entry.get("br") is None else int(entry["br"]),
@@ -248,4 +253,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

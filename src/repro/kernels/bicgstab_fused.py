@@ -14,10 +14,10 @@ collapse the iteration to three passes:
      with the three direction recurrences ``p' = r + β(p − ωs)``,
      ``s' = w + β(s − ωz)``, ``z' = t' + β(z − ωv)``.
 
-Both reuse the overlapping-window slab BlockSpec of ``stencil_spmv``;
-traced scalar coefficients ride a (1, k) block.  Partial accumulation
+Both reuse the overlapping-window x-slab BlockSpec of ``stencil_spmv``;
+traced scalar coefficients ride a (1, k) SMEM block.  Partial accumulation
 follows the sequential-TPU-grid idiom of ``spmv_dot.py`` (init at step 0,
-``+=`` on the revisited accumulator block), so the slab-ordered sums are
+``+=`` on the revisited accumulator block), so the plane-ordered sums are
 deterministic for a fixed tiling.  Oracles:
 ``kernels/ref.py::bicgstab_spmv_dots_ref`` / ``bicgstab_spmv_update_ref``.
 """
@@ -28,39 +28,36 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from repro.core.operators import Stencil
-from repro.kernels.stencil_spmv import _pick_bz, _window_spec, apply_stencil_slab
+from repro.kernels.blocks import (accumulate, acc_dtype, out_struct,
+                                  pallas_call, scalar_spec)
+from repro.kernels.stencil_spmv import (apply_stencil_plane, compiler_params,
+                                        plane_loop, slab_depth, slab_spec,
+                                        window_spec)
 
 
-def _dots_kernel(stencil: Stencil, nx: int, ny: int, bz: int):
+def _dots_kernel(stencil: Stencil, bx: int, ny: int, nz: int):
     def body(zin, coef, z, r, w, s, rhat, t, v_o, q_o, y_o, acc):
-        # zin: (nx+2, ny+2, bz+2) window; coef: (1, 1) = [α]; the six plain
-        # slabs and three outputs: (nx, ny, bz); acc: (1, 9) partials
+        # zin: (bx+2, ny+2, nz+2) window; coef: (1, 1) = [α]; the six plain
+        # slabs and three outputs: (bx, ny, nz); acc: (1, 9) partials
         alpha = coef[0, 0]
-        v = apply_stencil_slab(stencil, zin[...], nx, ny, bz)
-        q = r[...] - alpha * s[...]
-        y = w[...] - alpha * z[...]
-        rh = rhat[...]
-        v_o[...] = v
-        q_o[...] = q
-        y_o[...] = y
-        i = pl.program_id(0)
 
-        @pl.when(i == 0)
-        def _init():
-            acc[...] = jnp.zeros((1, 9), acc.dtype)
+        def plane(p, parts):
+            v = apply_stencil_plane(stencil, zin, p, ny, nz)
+            sp, zp = s[p], z[p]
+            q = r[p] - alpha * sp
+            y = w[p] - alpha * zp
+            rh = rhat[p]
+            v_o[p] = v
+            q_o[p] = q
+            y_o[p] = y
+            terms = (q * y, y * y, q * q, rh * q, rh * y, rh * t[p], rh * v,
+                     rh * zp, rh * sp)
+            return tuple(a + jnp.sum(b).astype(acc.dtype)
+                         for a, b in zip(parts, terms))
 
-        acc[0, 0] += jnp.sum(q * y).astype(acc.dtype)
-        acc[0, 1] += jnp.sum(y * y).astype(acc.dtype)
-        acc[0, 2] += jnp.sum(q * q).astype(acc.dtype)
-        acc[0, 3] += jnp.sum(rh * q).astype(acc.dtype)
-        acc[0, 4] += jnp.sum(rh * y).astype(acc.dtype)
-        acc[0, 5] += jnp.sum(rh * t[...]).astype(acc.dtype)
-        acc[0, 6] += jnp.sum(rh * v).astype(acc.dtype)
-        acc[0, 7] += jnp.sum(rh * z[...]).astype(acc.dtype)
-        acc[0, 8] += jnp.sum(rh * s[...]).astype(acc.dtype)
+        accumulate(acc, plane_loop(bx, plane, 9, acc.dtype))
 
     return body
 
@@ -78,7 +75,7 @@ def bicgstab_fused_spmv_dots(
     *,
     stencil: Stencil,
     bz: int = 8,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """``v = A·z̃`` + intermediates ``q, y`` + all 9 partials, one sweep.
 
@@ -88,44 +85,39 @@ def bicgstab_fused_spmv_dots(
     ``(q·y, y·y, q·q, r̂·q, r̂·y, r̂·t, r̂·v, r̂·z, r̂·s)``.
     """
     nx, ny, nz = zp.shape[0] - 2, zp.shape[1] - 2, zp.shape[2] - 2
-    bz = _pick_bz(nz, bz)
-    acc_dtype = jnp.float32 if zp.dtype == jnp.bfloat16 else zp.dtype
+    bx = slab_depth((nx, ny, nz), zp.dtype, bz, blocks=9)
     coef = alpha.astype(zp.dtype).reshape(1, 1)
-    slab = lambda: pl.BlockSpec((nx, ny, bz), lambda i: (0, 0, i))
+    slab = slab_spec(bx, ny, nz)
 
-    v, q, y, acc = pl.pallas_call(
-        _dots_kernel(stencil, nx, ny, bz),
-        grid=(nz // bz,),
-        in_specs=[
-            _window_spec(nx, ny, bz),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            slab(), slab(), slab(), slab(), slab(), slab(),
-        ],
-        out_specs=[
-            slab(), slab(), slab(),
-            pl.BlockSpec((1, 9), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nx, ny, nz), zp.dtype),
-            jax.ShapeDtypeStruct((nx, ny, nz), zp.dtype),
-            jax.ShapeDtypeStruct((nx, ny, nz), zp.dtype),
-            jax.ShapeDtypeStruct((1, 9), acc_dtype),
-        ],
+    v, q, y, acc = pallas_call(
+        _dots_kernel(stencil, bx, ny, nz),
+        grid=(nx // bx,),
+        in_specs=[window_spec(bx, ny, nz), scalar_spec()] + [slab] * 6,
+        out_specs=[slab] * 3 + [scalar_spec()],
+        out_shape=[out_struct((nx, ny, nz), zp.dtype, zp)] * 3
+        + [out_struct((1, 9), acc_dtype(zp.dtype), zp)],
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(zp, coef, z, r, w, s, rhat, t)
     return v, q, y, tuple(acc[0, k] for k in range(9))
 
 
-def _update_kernel(stencil: Stencil, nx: int, ny: int, bz: int):
-    def body(win, coef, w, r, p, s, z, v, t_o, p_o, s_o, z_o):
-        # win: (nx+2, ny+2, bz+2) window; coef: (1, 2) = [ω, β]
+def _update_kernel(stencil: Stencil, bx: int, ny: int, nz: int):
+    def body(win, coef, w, r, p_in, s, z, v, t_o, p_o, s_o, z_o):
+        # win: (bx+2, ny+2, nz+2) window; coef: (1, 2) = [ω, β]
         omega = coef[0, 0]
         beta = coef[0, 1]
-        t_new = apply_stencil_slab(stencil, win[...], nx, ny, bz)
-        t_o[...] = t_new
-        p_o[...] = r[...] + beta * (p[...] - omega * s[...])
-        s_o[...] = w[...] + beta * (s[...] - omega * z[...])
-        z_o[...] = t_new + beta * (z[...] - omega * v[...])
+
+        def plane(p, parts):
+            t_new = apply_stencil_plane(stencil, win, p, ny, nz)
+            sp, zp = s[p], z[p]
+            t_o[p] = t_new
+            p_o[p] = r[p] + beta * (p_in[p] - omega * sp)
+            s_o[p] = w[p] + beta * (sp - omega * zp)
+            z_o[p] = t_new + beta * (zp - omega * v[p])
+            return parts
+
+        plane_loop(bx, plane)
 
     return body
 
@@ -144,7 +136,7 @@ def bicgstab_fused_spmv_update(
     *,
     stencil: Stencil,
     bz: int = 8,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """``t' = A·w̃`` + the three direction recurrences, one sweep.
 
@@ -153,20 +145,17 @@ def bicgstab_fused_spmv_update(
     ``p' = r + β(p − ωs)``, ``s' = w + β(s − ωz)``, ``z' = t' + β(z − ωv)``.
     """
     nx, ny, nz = wp.shape[0] - 2, wp.shape[1] - 2, wp.shape[2] - 2
-    bz = _pick_bz(nz, bz)
+    bx = slab_depth((nx, ny, nz), wp.dtype, bz, blocks=10)
     coef = jnp.stack([omega, beta]).astype(wp.dtype).reshape(1, 2)
-    slab = lambda: pl.BlockSpec((nx, ny, bz), lambda i: (0, 0, i))
+    slab = slab_spec(bx, ny, nz)
 
-    outs = pl.pallas_call(
-        _update_kernel(stencil, nx, ny, bz),
-        grid=(nz // bz,),
-        in_specs=[
-            _window_spec(nx, ny, bz),
-            pl.BlockSpec((1, 2), lambda i: (0, 0)),
-            slab(), slab(), slab(), slab(), slab(), slab(),
-        ],
-        out_specs=[slab(), slab(), slab(), slab()],
-        out_shape=[jax.ShapeDtypeStruct((nx, ny, nz), wp.dtype)] * 4,
+    outs = pallas_call(
+        _update_kernel(stencil, bx, ny, nz),
+        grid=(nx // bx,),
+        in_specs=[window_spec(bx, ny, nz), scalar_spec()] + [slab] * 6,
+        out_specs=[slab] * 4,
+        out_shape=[out_struct((nx, ny, nz), wp.dtype, wp)] * 4,
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(wp, coef, w, r, p, s, z, v)
     return tuple(outs)

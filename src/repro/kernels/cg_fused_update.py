@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.fused_axpby import ROW, _to_2d
+from repro.kernels.blocks import (accumulate, acc_dtype, out_struct,
+                                  pallas_call, scalar_spec)
 
 
 def _kernel(*refs):
@@ -41,13 +43,7 @@ def _kernel(*refs):
     ap_new = ar[...] + beta * ap[...]
     p_out[...] = p_new
     ap_out[...] = ap_new
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        acc[0, 0] = jnp.zeros((), acc.dtype)
-
-    acc[0, 0] += jnp.sum(ap_new * p_new).astype(acc.dtype)
+    accumulate(acc, [jnp.sum(ap_new * p_new).astype(acc.dtype)])
 
 
 @functools.partial(jax.jit, static_argnames=("br", "interpret"))
@@ -59,7 +55,7 @@ def cg_fused_update(
     ap: jax.Array,
     *,
     br: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Returns ``(p_new, Ap_new, partial_dot)``."""
     shape = r.shape
@@ -71,18 +67,17 @@ def cg_fused_update(
     brr = min(br, rows)
     while rows % brr:
         brr -= 1
-    acc_dtype = jnp.float32 if r.dtype == jnp.bfloat16 else r.dtype
     coef = beta.astype(r.dtype).reshape(1, 1)
     blk = lambda: pl.BlockSpec((brr, ROW), lambda i: (i, 0))
-    p_new, ap_new, acc = pl.pallas_call(
+    p_new, ap_new, acc = pallas_call(
         _kernel,
         grid=(rows // brr,),
         in_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0)), blk(), blk(), blk(), blk()],
-        out_specs=[blk(), blk(), pl.BlockSpec((1, 1), lambda i: (0, 0))],
+        out_specs=[blk(), blk(), scalar_spec()],
         out_shape=[
-            jax.ShapeDtypeStruct(r2.shape, r.dtype),
-            jax.ShapeDtypeStruct(r2.shape, r.dtype),
-            jax.ShapeDtypeStruct((1, 1), acc_dtype),
+            out_struct(r2.shape, r.dtype, r),
+            out_struct(r2.shape, r.dtype, r),
+            out_struct((1, 1), acc_dtype(r.dtype), r),
         ],
         interpret=interpret,
     )(coef, r2, ar2, p2, ap2)
@@ -115,8 +110,10 @@ def fused_cg_body(
     s: jax.Array,
     w: jax.Array,
     *,
-    br: int = 128,   # 9 live blocks (5 in + 4 out): br=256 would double-buffer
-    interpret: bool = True,  # past 16 MiB VMEM (repro.analysis.lint_kernels)
+    # 9 live blocks (5 in + 4 out): br=256 would double-buffer past 16 MiB
+    # VMEM (repro.analysis.lint_kernels)
+    br: int = 128,
+    interpret: bool,
 ):
     """One merged-CG iteration's four vector updates in one VMEM pass.
 
@@ -136,13 +133,13 @@ def fused_cg_body(
         brr -= 1
     coef = jnp.stack([alpha, beta]).astype(x.dtype).reshape(1, 2)
     blk = lambda: pl.BlockSpec((brr, ROW), lambda i: (i, 0))
-    outs = pl.pallas_call(
+    outs = pallas_call(
         _body_kernel,
         grid=(rows // brr,),
         in_specs=[pl.BlockSpec((1, 2), lambda i: (0, 0)),
                   blk(), blk(), blk(), blk(), blk()],
         out_specs=[blk(), blk(), blk(), blk()],
-        out_shape=[jax.ShapeDtypeStruct(x2.shape, x.dtype)] * 4,
+        out_shape=[out_struct(x2.shape, x.dtype, x)] * 4,
         interpret=interpret,
     )(coef, x2, r2, p2, s2, w2)
     return tuple(o.reshape(-1)[:n].reshape(shape) for o in outs)

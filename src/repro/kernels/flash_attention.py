@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.blocks import out_struct, pallas_call
+
 NEG_INF = -2.3819763e38
 
 
@@ -76,7 +78,7 @@ def flash_attention(
     bq: int = 256,
     bkv: int = 256,
     window: int = 0,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     B, S, H, hd = q.shape
     while S % bq:
@@ -87,7 +89,7 @@ def flash_attention(
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, S, hd)
     kf = k.transpose(0, 2, 1, 3).reshape(B * H, S, hd)
     vf = v.transpose(0, 2, 1, 3).reshape(B * H, S, hd)
-    out = pl.pallas_call(
+    out = pallas_call(
         _kernel(bq, bkv, hd, scale, window),
         grid=(B * H, S // bq, S // bkv),
         in_specs=[
@@ -96,7 +98,7 @@ def flash_attention(
             pl.BlockSpec((1, bkv, hd), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, bq, hd), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, S, hd), q.dtype),
+        out_shape=out_struct((B * H, S, hd), q.dtype, q),
         scratch_shapes=[
             # (bq,) running max, (bq,) denominator, (bq, hd) accumulator —
             # persist across the sequential kv grid dim (VMEM on TPU)

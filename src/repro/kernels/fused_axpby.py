@@ -19,6 +19,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.blocks import (accumulate, acc_dtype, out_struct,
+                                  pallas_call, scalar_spec)
+
 #: lane-aligned row width used by the flat-vector wrappers
 ROW = 1024
 
@@ -35,13 +38,7 @@ def _kernel(fuse_dot: bool, br: int, cols: int):
         r = a * x[...] + b * y[...] + c * z[...]
         out[...] = r
         if fuse_dot:
-            i = pl.program_id(0)
-
-            @pl.when(i == 0)
-            def _init():
-                acc[0, 0] = jnp.zeros((), acc.dtype)
-
-            acc[0, 0] += jnp.sum(r * w[...]).astype(acc.dtype)
+            accumulate(acc, [jnp.sum(r * w[...]).astype(acc.dtype)])
 
     return body
 
@@ -64,7 +61,7 @@ def fused_axpby(
     z: jax.Array,
     *,
     br: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """``a·x + b·y + c·z`` elementwise, any (matching) shapes."""
     shape = x.shape
@@ -76,7 +73,7 @@ def fused_axpby(
     while rows % brr:
         brr -= 1
     coef = jnp.stack([a, b, c]).astype(x.dtype).reshape(1, 3)
-    out = pl.pallas_call(
+    out = pallas_call(
         _kernel(False, brr, ROW),
         grid=(rows // brr,),
         in_specs=[
@@ -86,7 +83,7 @@ def fused_axpby(
             pl.BlockSpec((brr, ROW), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((brr, ROW), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
+        out_shape=out_struct(x2.shape, x.dtype, x),
         interpret=interpret,
     )(coef, x2, y2, z2)
     return out.reshape(-1)[:n].reshape(shape)
@@ -103,7 +100,7 @@ def fused_axpby_dot(
     w: jax.Array,
     *,
     br: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """``out = a·x + b·y + c·z`` and the fused partial ``dot(out, w)``."""
     shape = x.shape
@@ -115,9 +112,8 @@ def fused_axpby_dot(
     brr = min(br, rows)
     while rows % brr:
         brr -= 1
-    acc_dtype = jnp.float32 if x.dtype == jnp.bfloat16 else x.dtype
     coef = jnp.stack([a, b, c]).astype(x.dtype).reshape(1, 3)
-    out, acc = pl.pallas_call(
+    out, acc = pallas_call(
         _kernel(True, brr, ROW),
         grid=(rows // brr,),
         in_specs=[
@@ -129,11 +125,11 @@ def fused_axpby_dot(
         ],
         out_specs=[
             pl.BlockSpec((brr, ROW), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            scalar_spec(),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(x2.shape, x.dtype),
-            jax.ShapeDtypeStruct((1, 1), acc_dtype),
+            out_struct(x2.shape, x.dtype, x),
+            out_struct((1, 1), acc_dtype(x.dtype), x),
         ],
         interpret=interpret,
     )(coef, x2, y2, z2, w2)

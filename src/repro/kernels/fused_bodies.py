@@ -36,6 +36,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.fused_axpby import ROW, _to_2d
+from repro.kernels.blocks import (accumulate, acc_dtype, out_struct,
+                                  pallas_call, scalar_spec)
 
 
 def _tile(v):
@@ -52,16 +54,10 @@ def _row_grid(rows: int, br: int) -> int:
 
 def _dots_kernel(*refs):
     a, b, c, acc = refs
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        acc[...] = jnp.zeros((1, 3), acc.dtype)
-
     av, bv, cv = a[...], b[...], c[...]
-    acc[0, 0] += jnp.sum(av * bv).astype(acc.dtype)
-    acc[0, 1] += jnp.sum(cv * bv).astype(acc.dtype)
-    acc[0, 2] += jnp.sum(av * av).astype(acc.dtype)
+    accumulate(acc, [jnp.sum(av * bv).astype(acc.dtype),
+                     jnp.sum(cv * bv).astype(acc.dtype),
+                     jnp.sum(av * av).astype(acc.dtype)])
 
 
 @functools.partial(jax.jit, static_argnames=("br", "interpret"))
@@ -71,7 +67,7 @@ def fused_dots(
     c: jax.Array,
     *,
     br: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Stacked partial dots ``(a·b, c·b, a·a)`` in ONE read pass.
 
@@ -83,14 +79,13 @@ def fused_dots(
     c2, _ = _tile(c)
     rows = a2.shape[0]
     brr = _row_grid(rows, br)
-    acc_dtype = jnp.float32 if a.dtype == jnp.bfloat16 else a.dtype
     blk = lambda: pl.BlockSpec((brr, ROW), lambda i: (i, 0))
-    acc = pl.pallas_call(
+    acc = pallas_call(
         _dots_kernel,
         grid=(rows // brr,),
         in_specs=[blk(), blk(), blk()],
-        out_specs=[pl.BlockSpec((1, 3), lambda i: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((1, 3), acc_dtype)],
+        out_specs=[scalar_spec()],
+        out_shape=[out_struct((1, 3), acc_dtype(a.dtype), a)],
         interpret=interpret,
     )(a2, b2, c2)[0]
     return acc[0, 0], acc[0, 1], acc[0, 2]
@@ -124,7 +119,7 @@ def fused_pipe_body(
     n: jax.Array,
     *,
     br: int = 64,   # 13 live blocks (7 in + 6 out): see lint_kernels budget
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Pipelined CG's six vector recurrences in one VMEM pass.
 
@@ -139,12 +134,12 @@ def fused_pipe_body(
     brr = _row_grid(rows, br)
     coef = jnp.stack([alpha, beta]).astype(x.dtype).reshape(1, 2)
     blk = lambda: pl.BlockSpec((brr, ROW), lambda i: (i, 0))
-    outs = pl.pallas_call(
+    outs = pallas_call(
         _pipe_kernel,
         grid=(rows // brr,),
         in_specs=[pl.BlockSpec((1, 2), lambda i: (0, 0))] + [blk()] * 7,
         out_specs=[blk()] * 6,
-        out_shape=[jax.ShapeDtypeStruct(tiles[0].shape, x.dtype)] * 6,
+        out_shape=[out_struct(tiles[0].shape, x.dtype, x)] * 6,
         interpret=interpret,
     )(coef, *tiles)
     return tuple(o.reshape(-1)[:nflat].reshape(shape) for o in outs)
@@ -174,7 +169,7 @@ def fused_pcg_body(
     w: jax.Array,
     *,
     br: int = 128,   # 10 live blocks (6 in + 4 out)
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Merged PCG's four vector updates in one VMEM pass.
 
@@ -189,12 +184,12 @@ def fused_pcg_body(
     brr = _row_grid(rows, br)
     coef = jnp.stack([alpha, beta]).astype(x.dtype).reshape(1, 2)
     blk = lambda: pl.BlockSpec((brr, ROW), lambda i: (i, 0))
-    outs = pl.pallas_call(
+    outs = pallas_call(
         _pcg_kernel,
         grid=(rows // brr,),
         in_specs=[pl.BlockSpec((1, 2), lambda i: (0, 0))] + [blk()] * 6,
         out_specs=[blk()] * 4,
-        out_shape=[jax.ShapeDtypeStruct(tiles[0].shape, x.dtype)] * 4,
+        out_shape=[out_struct(tiles[0].shape, x.dtype, x)] * 4,
         interpret=interpret,
     )(coef, *tiles)
     return tuple(o.reshape(-1)[:nflat].reshape(shape) for o in outs)
@@ -235,7 +230,7 @@ def fused_ppipe_body(
     n: jax.Array,
     *,
     br: int = 64,   # 18 live blocks (10 in + 8 out)
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Pipelined PCG's eight vector recurrences in one VMEM pass.
 
@@ -250,12 +245,12 @@ def fused_ppipe_body(
     brr = _row_grid(rows, br)
     coef = jnp.stack([alpha, beta]).astype(x.dtype).reshape(1, 2)
     blk = lambda: pl.BlockSpec((brr, ROW), lambda i: (i, 0))
-    outs = pl.pallas_call(
+    outs = pallas_call(
         _ppipe_kernel,
         grid=(rows // brr,),
         in_specs=[pl.BlockSpec((1, 2), lambda i: (0, 0))] + [blk()] * 10,
         out_specs=[blk()] * 8,
-        out_shape=[jax.ShapeDtypeStruct(tiles[0].shape, x.dtype)] * 8,
+        out_shape=[out_struct(tiles[0].shape, x.dtype, x)] * 8,
         interpret=interpret,
     )(coef, *tiles)
     return tuple(o.reshape(-1)[:nflat].reshape(shape) for o in outs)
@@ -282,7 +277,7 @@ def bicgstab_fused_update1(
     v: jax.Array,
     *,
     br: int = 128,   # 9 live blocks (6 in + 3 out)
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Single-reduction BiCGStab's ω-half updates in one VMEM pass.
 
@@ -297,12 +292,12 @@ def bicgstab_fused_update1(
     brr = _row_grid(rows, br)
     coef = jnp.stack([alpha, omega]).astype(y.dtype).reshape(1, 2)
     blk = lambda: pl.BlockSpec((brr, ROW), lambda i: (i, 0))
-    outs = pl.pallas_call(
+    outs = pallas_call(
         _bicgstab_u1_kernel,
         grid=(rows // brr,),
         in_specs=[pl.BlockSpec((1, 2), lambda i: (0, 0))] + [blk()] * 6,
         out_specs=[blk()] * 3,
-        out_shape=[jax.ShapeDtypeStruct(tiles[0].shape, y.dtype)] * 3,
+        out_shape=[out_struct(tiles[0].shape, y.dtype, y)] * 3,
         interpret=interpret,
     )(coef, *tiles)
     return tuple(o.reshape(-1)[:nflat].reshape(shape) for o in outs)

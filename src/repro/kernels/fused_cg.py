@@ -17,7 +17,7 @@ of any fused-capable method here (and to the shard_map equivalent on a
 mesh — see ``core.distributed.solve_shardmap(pallas_fused=True)``).
 
 Numerics: identical recurrence to ``cg_merged``; the fused dot partials
-accumulate per z-slab instead of in jnp's reduction order, so iterates
+accumulate per x-plane instead of in jnp's reduction order, so iterates
 agree to machine precision but not bit-for-bit
 (tests/test_reduction_hiding.py pins the tolerance).
 """

@@ -10,6 +10,7 @@ can run on either implementation (tests assert they agree).
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 
 from repro.core.operators import Stencil
 from repro.kernels.cg_fused_update import (
@@ -46,6 +47,16 @@ from repro.kernels.stencil_spmv import stencil_spmv as _stencil_spmv
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def check_dtype(dtype) -> None:
+    """Refuse a float64 problem where the kernels compile for the chip: the
+    TPU's kernel compiler lowers no 64-bit floats (interpret mode runs it)."""
+    if not _interpret() and jnp.dtype(dtype) == jnp.float64:
+        raise ValueError(
+            "the Pallas kernels run in float32 or bfloat16 on a TPU; solve "
+            "this float64 problem with pallas=False (XLA), or in float32 "
+            "(SolverOptions(f64=False))")
 
 
 def spmv(xp: jax.Array, stencil: Stencil, *, bz: int = 8) -> jax.Array:

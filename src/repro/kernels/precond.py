@@ -1,7 +1,7 @@
 """Pallas TPU kernels for the preconditioner hot paths.
 
-Two fused kernels, both on the ``stencil_spmv`` overlapping-window z-slab
-tiling ((nx+2, ny+2, bz+2) VMEM windows, HBM traffic (bz+2)/bz):
+Two fused kernels, both on the ``stencil_spmv`` overlapping-window x-slab
+tiling ((bx+2, ny+2, nz+2) VMEM windows, HBM traffic (bx+2)/bx):
 
   * ``cheb_fused_step`` — one Chebyshev recurrence step in ONE VMEM pass:
     the stencil apply ``A z`` plus the whole axpby chain
@@ -28,20 +28,25 @@ from __future__ import annotations
 import functools
 
 import jax
-from jax.experimental import pallas as pl
 
 from repro.core.operators import Stencil
-from repro.kernels.stencil_spmv import _pick_bz, _window_spec, apply_stencil_slab
+from repro.kernels.blocks import out_struct, pallas_call
+from repro.kernels.stencil_spmv import (apply_stencil_plane, centre_plane,
+                                        compiler_params, plane_loop,
+                                        slab_depth, slab_spec, window_spec)
 
 
-def _cheb_kernel(stencil: Stencil, nx: int, ny: int, bz: int,
+def _cheb_kernel(stencil: Stencil, bx: int, ny: int, nz: int,
                  a: float, c: float):
     def body(zin, rin, din, zout, dout):
-        z_slab = zin[...]
-        az = apply_stencil_slab(stencil, z_slab, nx, ny, bz)
-        d_new = a * din[...] + c * (rin[...] - az)
-        dout[...] = d_new
-        zout[...] = z_slab[1:-1, 1:-1, 1:-1] + d_new
+        def plane(p, parts):
+            az = apply_stencil_plane(stencil, zin, p, ny, nz)
+            d_new = a * din[p] + c * (rin[p] - az)
+            dout[p] = d_new
+            zout[p] = centre_plane(zin, p, ny, nz) + d_new
+            return parts
+
+        plane_loop(bx, plane)
 
     return body
 
@@ -58,7 +63,7 @@ def cheb_fused_step(
     a: float,
     c: float,
     bz: int = 8,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """One fused Chebyshev step from the halo-padded ``zp``.
 
@@ -66,27 +71,29 @@ def cheb_fused_step(
     ``z_new = z + d_new``; shapes (nx, ny, nz) from ``zp``'s interior.
     """
     nx, ny, nz = r.shape
-    bzz = _pick_bz(nz, bz)
-    slab = pl.BlockSpec((nx, ny, bzz), lambda i: (0, 0, i))
-    z_new, d_new = pl.pallas_call(
-        _cheb_kernel(stencil, nx, ny, bzz, a, c),
-        grid=(nz // bzz,),
-        in_specs=[_window_spec(nx, ny, bzz), slab, slab],
+    bx = slab_depth(r.shape, r.dtype, bz, blocks=4)
+    slab = slab_spec(bx, ny, nz)
+    z_new, d_new = pallas_call(
+        _cheb_kernel(stencil, bx, ny, nz, a, c),
+        grid=(nx // bx,),
+        in_specs=[window_spec(bx, ny, nz), slab, slab],
         out_specs=[slab, slab],
-        out_shape=[
-            jax.ShapeDtypeStruct((nx, ny, nz), r.dtype),
-            jax.ShapeDtypeStruct((nx, ny, nz), r.dtype),
-        ],
+        out_shape=[out_struct((nx, ny, nz), r.dtype, r)] * 2,
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(zp, r, d)
     return z_new, d_new
 
 
-def _bj_kernel(stencil: Stencil, nx: int, ny: int, bz: int, omega: float):
+def _bj_kernel(stencil: Stencil, bx: int, ny: int, nz: int, omega: float):
     def body(zin, rin, out):
-        z_slab = zin[...]
-        az = apply_stencil_slab(stencil, z_slab, nx, ny, bz)
-        out[...] = z_slab[1:-1, 1:-1, 1:-1] + omega * (rin[...] - az) / stencil.diag
+        def plane(p, parts):
+            az = apply_stencil_plane(stencil, zin, p, ny, nz)
+            out[p] = (centre_plane(zin, p, ny, nz)
+                      + omega * (rin[p] - az) / stencil.diag)
+            return parts
+
+        plane_loop(bx, plane)
 
     return body
 
@@ -101,19 +108,18 @@ def block_jacobi_sweep(
     stencil: Stencil,
     omega: float = 1.0,
     bz: int = 8,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """``z + ω·(r - A z)/diag`` from the zero-padded local ``zp``, one pass."""
     nx, ny, nz = r.shape
-    bzz = _pick_bz(nz, bz)
-    return pl.pallas_call(
-        _bj_kernel(stencil, nx, ny, bzz, omega),
-        grid=(nz // bzz,),
-        in_specs=[
-            _window_spec(nx, ny, bzz),
-            pl.BlockSpec((nx, ny, bzz), lambda i: (0, 0, i)),
-        ],
-        out_specs=pl.BlockSpec((nx, ny, bzz), lambda i: (0, 0, i)),
-        out_shape=jax.ShapeDtypeStruct((nx, ny, nz), r.dtype),
+    bx = slab_depth(r.shape, r.dtype, bz, blocks=2)
+    slab = slab_spec(bx, ny, nz)
+    return pallas_call(
+        _bj_kernel(stencil, bx, ny, nz, omega),
+        grid=(nx // bx,),
+        in_specs=[window_spec(bx, ny, nz), slab],
+        out_specs=slab,
+        out_shape=out_struct((nx, ny, nz), r.dtype, r),
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(zp, r)

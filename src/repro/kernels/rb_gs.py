@@ -5,9 +5,10 @@ One colour's update, fused with the stencil application:
     x[i,j,k] <- (b[i,j,k] - Σ_off c·x[neigh]) / diag     where (i+j+k)%2 == colour
     x[i,j,k] <- x[i,j,k]                                  otherwise
 
-Same z-slab overlapping-window tiling as ``stencil_spmv``; the parity mask is
-built from iotas plus the grid step's global z offset.  The colour is a
-Python static (two specialisations), mirroring the paper's two-colour scheme.
+Same x-slab overlapping-window tiling as ``stencil_spmv``; the parity mask is
+built per plane from (y, z) iotas plus the plane's global x index.  The
+colour is a Python static (two specialisations), mirroring the paper's
+two-colour scheme.
 """
 
 from __future__ import annotations
@@ -19,31 +20,26 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.operators import Stencil
-from repro.kernels.stencil_spmv import _window_spec
+from repro.kernels.blocks import out_struct, pallas_call
+from repro.kernels.stencil_spmv import (apply_stencil_plane, centre_plane,
+                                        compiler_params, plane_loop,
+                                        slab_depth, slab_spec, window_spec)
 
 
-def _kernel(stencil: Stencil, nx: int, ny: int, bz: int, colour: int):
-    off_groups: dict[int, list[tuple[int, int]]] = {-1: [], 0: [], 1: []}
-    for dx, dy, dz in stencil.offsets:
-        off_groups[dz].append((dx, dy))
-
+def _kernel(stencil: Stencil, bx: int, ny: int, nz: int, colour: int):
     def body(xin, bin_, out):
-        x_slab = xin[...]
-        centre = x_slab[1:-1, 1:-1, 1:-1]
-        off = jnp.zeros((nx, ny, bz), x_slab.dtype)
-        for dz, xy in off_groups.items():
-            zsl = x_slab[:, :, 1 + dz : 1 + dz + bz]
-            for dx, dy in xy:
-                off = off + stencil.off_coeff * zsl[
-                    1 + dx : 1 + dx + nx, 1 + dy : 1 + dy + ny, :
-                ]
-        gs = (bin_[...] - off) / stencil.diag
-        i = pl.program_id(0)
-        ii = jax.lax.broadcasted_iota(jnp.int32, (nx, ny, bz), 0)
-        jj = jax.lax.broadcasted_iota(jnp.int32, (nx, ny, bz), 1)
-        kk = jax.lax.broadcasted_iota(jnp.int32, (nx, ny, bz), 2) + i * bz
-        mask = ((ii + jj + kk) % 2) == colour
-        out[...] = jnp.where(mask, gs, centre)
+        jj = jax.lax.broadcasted_iota(jnp.int32, (ny, nz), 0)
+        kk = jax.lax.broadcasted_iota(jnp.int32, (ny, nz), 1)
+        x0 = pl.program_id(0) * bx
+
+        def plane(p, parts):
+            off = apply_stencil_plane(stencil, xin, p, ny, nz, diag=False)
+            gs = (bin_[p] - off) / stencil.diag
+            mask = ((x0 + p + jj + kk) % 2) == colour
+            out[p] = jnp.where(mask, gs, centre_plane(xin, p, ny, nz))
+            return parts
+
+        plane_loop(bx, plane)
 
     return body
 
@@ -56,21 +52,17 @@ def rb_gs_half_sweep(
     stencil: Stencil,
     colour: int,
     bz: int = 8,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """One coloured half-sweep from padded ``xp``; returns the updated grid."""
     nx, ny, nz = b.shape
-    bzz = min(bz, nz)
-    while nz % bzz:
-        bzz -= 1
-    return pl.pallas_call(
-        _kernel(stencil, nx, ny, bzz, colour),
-        grid=(nz // bzz,),
-        in_specs=[
-            _window_spec(nx, ny, bzz),
-            pl.BlockSpec((nx, ny, bzz), lambda i: (0, 0, i)),
-        ],
-        out_specs=pl.BlockSpec((nx, ny, bzz), lambda i: (0, 0, i)),
-        out_shape=jax.ShapeDtypeStruct((nx, ny, nz), b.dtype),
+    bx = slab_depth(b.shape, b.dtype, bz, blocks=2)
+    return pallas_call(
+        _kernel(stencil, bx, ny, nz, colour),
+        grid=(nx // bx,),
+        in_specs=[window_spec(bx, ny, nz), slab_spec(bx, ny, nz)],
+        out_specs=slab_spec(bx, ny, nz),
+        out_shape=out_struct((nx, ny, nz), b.dtype, b),
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(xp, b)

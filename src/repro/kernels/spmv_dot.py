@@ -9,9 +9,9 @@ into a single VMEM pass: the memory-side analogue of stacking the two
 ``MPI_Allreduce``s into one.
 
 Extends ``kernels/stencil_spmv.py``'s ``fuse_dot`` (which emits only
-``(A x)·x``) with the second accumulator; same overlapping-window BlockSpec,
-same sequential-grid accumulation (TPU grid steps run in order, so the
-revisited (1, 2) accumulator block is well-defined).  Oracle:
+``(A x)·x``) with the second accumulator; same overlapping-window x-slab
+BlockSpec, same sequential-grid accumulation (TPU grid steps run in order,
+so the revisited (1, 2) SMEM accumulator is well-defined).  Oracle:
 ``kernels/ref.py::stencil_spmv_dots_ref``.
 
 ``stencil_spmv_dots3`` (PR 10) is the same pass with a second (unpadded)
@@ -28,28 +28,27 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from repro.core.operators import Stencil
-from repro.kernels.stencil_spmv import _pick_bz, _window_spec, apply_stencil_slab
+from repro.kernels.blocks import (accumulate, acc_dtype, out_struct,
+                                  pallas_call, scalar_spec)
+from repro.kernels.stencil_spmv import (apply_stencil_plane, centre_plane,
+                                        compiler_params, plane_loop,
+                                        slab_depth, slab_spec, window_spec)
 
 
-def _kernel(stencil: Stencil, nx: int, ny: int, bz: int):
+def _kernel(stencil: Stencil, bx: int, ny: int, nz: int):
     def body(xin, out, acc):
-        # xin: (nx+2, ny+2, bz+2) overlapping window; out: (nx, ny, bz);
+        # xin: (bx+2, ny+2, nz+2) overlapping window; out: (bx, ny, nz);
         # acc: (1, 2) = [Σ y·x, Σ x·x] partials, revisited every grid step
-        x_slab = xin[...]
-        centre = x_slab[1:-1, 1:-1, 1:-1]
-        y = apply_stencil_slab(stencil, x_slab, nx, ny, bz)
-        out[...] = y
-        i = pl.program_id(0)
+        def plane(p, parts):
+            y = apply_stencil_plane(stencil, xin, p, ny, nz)
+            out[p] = y
+            c = centre_plane(xin, p, ny, nz)
+            return (parts[0] + jnp.sum(y * c).astype(acc.dtype),
+                    parts[1] + jnp.sum(c * c).astype(acc.dtype))
 
-        @pl.when(i == 0)
-        def _init():
-            acc[...] = jnp.zeros((1, 2), acc.dtype)
-
-        acc[0, 0] += jnp.sum(y * centre).astype(acc.dtype)
-        acc[0, 1] += jnp.sum(centre * centre).astype(acc.dtype)
+        accumulate(acc, plane_loop(bx, plane, 2, acc.dtype))
 
     return body
 
@@ -60,7 +59,7 @@ def stencil_spmv_dots(
     *,
     stencil: Stencil,
     bz: int = 8,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """``y = A·x``, ``y·x`` and ``x·x`` from the halo-padded ``xp``.
 
@@ -68,44 +67,37 @@ def stencil_spmv_dots(
     with ``x = r``: ``w = A r``, ``δ`` and ``γ`` in one HBM pass.
     """
     nx, ny, nz = xp.shape[0] - 2, xp.shape[1] - 2, xp.shape[2] - 2
-    bz = _pick_bz(nz, bz)
-    acc_dtype = jnp.float32 if xp.dtype == jnp.bfloat16 else xp.dtype
+    bx = slab_depth((nx, ny, nz), xp.dtype, bz, blocks=1)
 
-    y, acc = pl.pallas_call(
-        _kernel(stencil, nx, ny, bz),
-        grid=(nz // bz,),
-        in_specs=[_window_spec(nx, ny, bz)],
-        out_specs=[
-            pl.BlockSpec((nx, ny, bz), lambda i: (0, 0, i)),
-            pl.BlockSpec((1, 2), lambda i: (0, 0)),
-        ],
+    y, acc = pallas_call(
+        _kernel(stencil, bx, ny, nz),
+        grid=(nx // bx,),
+        in_specs=[window_spec(bx, ny, nz)],
+        out_specs=[slab_spec(bx, ny, nz), scalar_spec()],
         out_shape=[
-            jax.ShapeDtypeStruct((nx, ny, nz), xp.dtype),
-            jax.ShapeDtypeStruct((1, 2), acc_dtype),
+            out_struct((nx, ny, nz), xp.dtype, xp),
+            out_struct((1, 2), acc_dtype(xp.dtype), xp),
         ],
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(xp)
     return y, acc[0, 0], acc[0, 1]
 
 
-def _kernel3(stencil: Stencil, nx: int, ny: int, bz: int):
+def _kernel3(stencil: Stencil, bx: int, ny: int, nz: int):
     def body(xin, rin, out, acc):
-        # xin: (nx+2, ny+2, bz+2) overlapping window; rin/out: (nx, ny, bz);
+        # xin: (bx+2, ny+2, nz+2) overlapping window; rin/out: (bx, ny, nz);
         # acc: (1, 3) = [Σ y·x, Σ r·x, Σ r·r] partials, revisited per step
-        x_slab = xin[...]
-        centre = x_slab[1:-1, 1:-1, 1:-1]
-        r_slab = rin[...]
-        y = apply_stencil_slab(stencil, x_slab, nx, ny, bz)
-        out[...] = y
-        i = pl.program_id(0)
+        def plane(p, parts):
+            y = apply_stencil_plane(stencil, xin, p, ny, nz)
+            out[p] = y
+            c = centre_plane(xin, p, ny, nz)
+            r = rin[p]
+            return (parts[0] + jnp.sum(y * c).astype(acc.dtype),
+                    parts[1] + jnp.sum(r * c).astype(acc.dtype),
+                    parts[2] + jnp.sum(r * r).astype(acc.dtype))
 
-        @pl.when(i == 0)
-        def _init():
-            acc[...] = jnp.zeros((1, 3), acc.dtype)
-
-        acc[0, 0] += jnp.sum(y * centre).astype(acc.dtype)
-        acc[0, 1] += jnp.sum(r_slab * centre).astype(acc.dtype)
-        acc[0, 2] += jnp.sum(r_slab * r_slab).astype(acc.dtype)
+        accumulate(acc, plane_loop(bx, plane, 3, acc.dtype))
 
     return body
 
@@ -117,7 +109,7 @@ def stencil_spmv_dots3(
     *,
     stencil: Stencil,
     bz: int = 8,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """``y = A·x`` plus the THREE partials ``(y·x, r·x, r·r)``, one pass.
 
@@ -127,24 +119,18 @@ def stencil_spmv_dots3(
     ``r·w``/``r·r`` slots.
     """
     nx, ny, nz = xp.shape[0] - 2, xp.shape[1] - 2, xp.shape[2] - 2
-    bz = _pick_bz(nz, bz)
-    acc_dtype = jnp.float32 if xp.dtype == jnp.bfloat16 else xp.dtype
+    bx = slab_depth((nx, ny, nz), xp.dtype, bz, blocks=2)
 
-    y, acc = pl.pallas_call(
-        _kernel3(stencil, nx, ny, bz),
-        grid=(nz // bz,),
-        in_specs=[
-            _window_spec(nx, ny, bz),
-            pl.BlockSpec((nx, ny, bz), lambda i: (0, 0, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((nx, ny, bz), lambda i: (0, 0, i)),
-            pl.BlockSpec((1, 3), lambda i: (0, 0)),
-        ],
+    y, acc = pallas_call(
+        _kernel3(stencil, bx, ny, nz),
+        grid=(nx // bx,),
+        in_specs=[window_spec(bx, ny, nz), slab_spec(bx, ny, nz)],
+        out_specs=[slab_spec(bx, ny, nz), scalar_spec()],
         out_shape=[
-            jax.ShapeDtypeStruct((nx, ny, nz), xp.dtype),
-            jax.ShapeDtypeStruct((1, 3), acc_dtype),
+            out_struct((nx, ny, nz), xp.dtype, xp),
+            out_struct((1, 3), acc_dtype(xp.dtype), xp),
         ],
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(xp, r)
     return y, acc[0, 0], acc[0, 1], acc[0, 2]

@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.analysis.hlo import collective_bytes, count_collectives
-from repro.core.compat import cost_analysis
 from repro.configs.base import SHAPES, all_configs, get_config
 from repro.distributed.sharding import (
     batch_shardings,
@@ -136,7 +135,7 @@ def _layer_cost(ctx, params_shape, batch, kind: str):
     else:
         fn = jax.jit(group_fwd, in_shardings=(lp_shard, h_shard, pos_shard))
     compiled = fn.lower(layer_shapes, h_shape, pos).compile()
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     cb = collective_bytes(compiled.as_text())
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes": float(ca.get("bytes accessed", 0.0)),
@@ -198,7 +197,7 @@ def _decode_layer_cost(ctx, params_shape, batch):
     h_shard = NamedSharding(ctx.mesh, P(dp_spec, None, None))
     fn = jax.jit(group, in_shardings=(lp_shard, h_shard, None, cache_shard))
     compiled = fn.lower(layer_shapes, h_shape, pos_shape, cache_shapes).compile()
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     cb = collective_bytes(compiled.as_text())
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes": float(ca.get("bytes accessed", 0.0)),
@@ -267,7 +266,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *,
 
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     hlo = compiled.as_text()
     n_coll = count_collectives(hlo)
     cb_raw = collective_bytes(hlo)
@@ -358,7 +357,7 @@ def run_solver_cell(method: str, stencil: str, mesh_kind: str, *,
     lowered = jax.jit(fn).lower(*args)
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     hlo = compiled.as_text()
     rec = {
         "method": method, "stencil": stencil, "mesh": mesh_kind,

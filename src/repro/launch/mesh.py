@@ -34,3 +34,11 @@ def make_solver_mesh(n: int | None = None) -> Mesh:
     """1-D mesh for the paper-faithful HPCCG layout (z-only decomposition)."""
     n = n or len(jax.devices())
     return make_mesh((n,), ("cells",))
+
+
+def make_solver_mesh_2d(n: int | None = None) -> Mesh:
+    """Near-square ('data', 'model') mesh for the 2-D x/y block layout:
+    2×2 on four chips, 4×2 on eight."""
+    n = n or len(jax.devices())
+    model = max(m for m in range(1, int(n ** 0.5) + 1) if n % m == 0)
+    return make_mesh((n // model, model), ("data", "model"))

@@ -27,7 +27,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
-from repro.core.compat import pvary, shard_map
 from repro.models.mlp import _act
 
 
@@ -97,7 +96,7 @@ def moe_forward(p, cfg: ArchConfig, x, mesh: Mesh, dp_axes: tuple[str, ...],
 
         y0 = jnp.zeros((T, d), xt.dtype)
         # match the scan carry's varying-manual-axes to the body output
-        y0 = pvary(y0, tuple(dp_axes) + (tp_axis,))
+        y0 = lax.pcast(y0, tuple(dp_axes) + (tp_axis,), to="varying")
         out, _ = lax.scan(body, y0, (flat_t, le, mine, flat_w))
         if cfg.shared_expert:
             sh_in, sh_out = shared
@@ -210,7 +209,7 @@ def moe_forward(p, cfg: ArchConfig, x, mesh: Mesh, dp_axes: tuple[str, ...],
         shared = (p["shared_in"], p["shared_out"])
     else:
         shared = (jnp.zeros((1, 2), x.dtype), jnp.zeros((1, 1), x.dtype))
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(

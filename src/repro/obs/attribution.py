@@ -65,7 +65,6 @@ def _phase_fns(problem, method: str, mesh, *, halo_mode: str, inner: int):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.compat import shard_map
     from repro.core.distributed import (DistributedOp, init_step_state,
                                         make_layout, solve_step_shardmap)
     from repro.core.solvers import LocalOp
@@ -92,7 +91,7 @@ def _phase_fns(problem, method: str, mesh, *, halo_mode: str, inner: int):
 
         return lax.fori_loop(0, inner, body, x_loc)
 
-    halo_chain = jax.jit(shard_map(local_halo, mesh=mesh, in_specs=(spec,),
+    halo_chain = jax.jit(jax.shard_map(local_halo, mesh=mesh, in_specs=(spec,),
                                    out_specs=spec))
 
     def local_reduce(x_loc):
@@ -106,7 +105,7 @@ def _phase_fns(problem, method: str, mesh, *, halo_mode: str, inner: int):
         return lax.fori_loop(0, inner, body,
                              (x_loc, jnp.zeros((), x_loc.dtype)))[1]
 
-    reduce_chain = jax.jit(shard_map(local_reduce, mesh=mesh,
+    reduce_chain = jax.jit(jax.shard_map(local_reduce, mesh=mesh,
                                      in_specs=(spec,), out_specs=P()))
 
     state0 = init_step_state(method, LocalOp(stencil), problem.b(),

@@ -101,7 +101,7 @@ def test_solve_batched_facade_and_validation(problem):
 
 
 def test_batched_bicgstab_b1_vmaps(problem):
-    """The optimization_barrier in Alg. 2 must be batchable (compat rule)."""
+    """The optimization_barrier in Alg. 2 must be batchable (JAX batches it)."""
     bs = jnp.stack([problem.b()] * 2)
     res = solve_batched(bs, problem, method="bicgstab_b1", tol=1e-6,
                         maxiter=200)

@@ -46,6 +46,15 @@ def test_default_table_large_grid_uses_pallas_on_tpu_only():
     assert default_decision((64, 64, 64), backend="tpu").bz == DEFAULT_BZ
 
 
+def test_default_table_keeps_f64_on_xla_on_tpu():
+    """The TPU's kernel compiler lowers no float64: pallas=None routes an
+    f64 solve to XLA whatever its size."""
+    assert not default_decision((128, 128, 128), backend="tpu",
+                                dtype=jnp.float64).use_pallas
+    assert default_decision((128, 128, 128), backend="tpu",
+                            dtype=jnp.float32).use_pallas
+
+
 def test_resolve_without_cache_is_the_default_table(cache):
     dec = resolve("7pt", (64, 64, 64), jnp.float64)
     assert dec.source == "default"

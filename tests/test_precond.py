@@ -318,7 +318,6 @@ import numpy as np
 import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from repro.api import SolverOptions, SolverSession
-from repro.core.compat import shard_map
 from repro.core.distributed import DistributedOp, make_layout, solve_shardmap
 from repro.core.problems import make_problem
 from repro.core.solvers import LocalOp

@@ -1,0 +1,104 @@
+"""Compile the main path's kernels and solve loop for a described TPU v5e.
+
+No chip is needed: the TPU compiler is installed, and it compiles for a
+topology that is described and not attached.  What interpret-mode tests
+cannot show — a block layout Mosaic refuses, a kernel over the scoped VMEM,
+a float64 kernel — fails here, at the production size (128³).
+
+The topology is described inside a module fixture, never at import time:
+only one process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.operators import STENCIL_27PT
+from repro.core.solvers import SOLVERS, LocalOp
+from repro.kernels import ops
+from repro.kernels.cg_fused_update import fused_cg_body
+from repro.kernels.rb_gs import rb_gs_half_sweep
+from repro.kernels.spmv_dot import stencil_spmv_dots
+from repro.kernels.stencil_spmv import stencil_spmv
+
+N = 128
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache; keep it out of the cache
+    prev_cache = jax.config.jax_enable_compilation_cache
+    prev_x64 = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    # the solvers run with x64 on; the kernels must compile under it
+    jax.config.update("jax_enable_x64", True)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_x64", prev_x64)
+    jax.config.update("jax_enable_compilation_cache", prev_cache)
+
+
+def _arg(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _kernel_cases():
+    st = STENCIL_27PT
+    padded, grid, scalar = (N + 2,) * 3, (N,) * 3, ()
+    return {
+        "stencil_spmv": (
+            lambda xp: stencil_spmv(xp, stencil=st, interpret=False),
+            (padded,)),
+        "stencil_spmv_dots": (
+            lambda xp: stencil_spmv_dots(xp, stencil=st, interpret=False),
+            (padded,)),
+        "rb_gs": (
+            lambda xp, b: rb_gs_half_sweep(xp, b, stencil=st, colour=0,
+                                           interpret=False),
+            (padded, grid)),
+        "fused_cg_body": (
+            lambda *a: fused_cg_body(*a, interpret=False),
+            (scalar, scalar) + (grid,) * 5),
+    }
+
+
+@pytest.mark.parametrize("name", sorted(_kernel_cases()))
+def test_kernel_compiles_for_v5e(one_chip, name):
+    fn, shapes = _kernel_cases()[name]
+    args = [_arg(s, jnp.float32, one_chip) for s in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_xla_cg_loop_compiles_in_f64_for_v5e(one_chip):
+    cg = SOLVERS["cg"]
+    op = LocalOp(STENCIL_27PT)
+    b = _arg((N,) * 3, jnp.float64, one_chip)
+    compiled = jax.jit(
+        lambda b, x0: cg(op, b, x0, tol=1e-6, maxiter=600)).lower(b, b).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    # x, r, p, Ap and b at 128³ in f64 fit a 16 GB chip many times over
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 2e9
+
+
+def test_f64_pallas_request_raises_before_compiling(one_chip, monkeypatch):
+    from repro.api import SolverOptions, SolverSession
+    # steer the session as it is steered on a TPU backend
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    with pytest.raises(ValueError, match="float32 or bfloat16"):
+        SolverSession(method="cg_merged", grid=(N,) * 3, stencil="27pt",
+                      options=SolverOptions(pallas=True))
+    # float32 is accepted
+    SolverSession(method="cg_merged", grid=(N,) * 3, stencil="27pt",
+                  options=SolverOptions(pallas=True, f64=False))
