@@ -115,9 +115,8 @@ def iteration_breakdown(method: str, nbar: int,
     0 (the default) or a method with no refresh hook prices as 0.
 
     Returns the per-phase split ``{"t_mem", "t_halo", "t_precond",
-    "t_reduce", "t_rr", "total"}`` — the prediction
-    ``repro.obs.attribution`` lines up against measured phase times;
-    :func:`iteration_time` is its ``total``.
+    "t_reduce", "t_rr", "total"}``; :func:`iteration_time` is its
+    ``total``.
     """
     r = local_grid[0] * local_grid[1] * local_grid[2]
     m = METHODS[method]

@@ -41,6 +41,7 @@ from repro.core.methods import (STATUS_BREAKDOWN, STATUS_DIVERGED,
 from repro.core.problems import HPCGProblem, make_problem
 from repro.core.solvers import LocalOp, SolveResult
 from repro.obs import trace as obs
+from repro.obs.scopes import op_scopes
 
 #: guarded exit statuses the recovery policies act on
 _RECOVERABLE = (STATUS_BREAKDOWN, STATUS_DIVERGED, STATUS_STAGNATED)
@@ -98,7 +99,7 @@ class SolverSession:
             from repro.kernels import ops
             ops.check_dtype(problem.dtype)
         # solve-lifecycle spans (repro.obs): resolve -> precond.setup ->
-        # compile (in _executable) -> execute (in solve/solve_batched)
+        # solve (inputs, with compile in _executable on a miss; execute)
         with obs.span("resolve", method=method, layout=self.options.layout,
                       grid=list(problem.shape)):
             self.spec: SolverSpec = get_solver(method)
@@ -297,6 +298,17 @@ class SolverSession:
         these counters."""
         return {k: dict(v) for k, v in self._compile_stats.items()}
 
+    def op_scopes(self) -> dict[tuple[str, str], str]:
+        """``{(HLO module, op name): repro.* scope}`` over every
+        executable the session has compiled, read from their metadata
+        (``repro.obs.scopes``; docs/API.md §Observability).  Computed on
+        each call, never at compile time."""
+        out: dict[tuple[str, str], str] = {}
+        for ex in (*self._executables.values(), self._timed_fn):
+            if ex is not None:
+                out.update(op_scopes(ex))
+        return out
+
     def _abstract(self, shape: tuple, *, batched: bool = False):
         dt = jnp.dtype(self.problem.dtype)
         sh = self.backend.sharding()
@@ -326,13 +338,15 @@ class SolverSession:
         with obs.span("solve", method=self.method,
                       grid=list(self.problem.shape),
                       backend=self.backend.kind):
-            b = self.problem.b() if b is None else b
-            x0 = self.problem.x0() if x0 is None else x0
-            fn = self._executable(
-                tuple(self.problem.shape), self._build_fn,
-                (self._abstract(tuple(self.problem.shape)),) * 2)
+            with obs.span("inputs"):
+                b = self.problem.b() if b is None else b
+                x0 = self.problem.x0() if x0 is None else x0
+                fn = self._executable(
+                    tuple(self.problem.shape), self._build_fn,
+                    (self._abstract(tuple(self.problem.shape)),) * 2)
+                b, x0 = self._place(b), self._place(x0)
             with obs.span("execute") as sp:
-                res = fn(self._place(b), self._place(x0))
+                res = fn(b, x0)
                 if sp is not None:
                     # only when tracing: block so the span times the solve,
                     # not the async dispatch (result semantics unchanged)

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.methods import Ops, get_method, run_method
+from repro.core.methods import Ops, get_method, in_scope, run_method
 from repro.core.operators import Stencil, interior_matvec, shell_assemble
 from repro.core.problems import HPCGProblem
 from repro.core.solvers import SolveResult
@@ -115,6 +115,7 @@ class DistributedOp:
             return self._pad_exchange_scatter(x)
         return self._pad_exchange_concat(x)
 
+    @in_scope("repro.halo")
     def _pad_exchange_scatter(self, x: jax.Array) -> jax.Array:
         """Baseline: zero-pad then scatter received planes into the halos.
 
@@ -146,6 +147,7 @@ class DistributedOp:
             xp = xp.at[tuple(halo_hi)].set(down)
         return xp
 
+    @in_scope("repro.halo")
     def _pad_exchange_concat(self, x: jax.Array) -> jax.Array:
         """Optimised: build the padded array by per-dim concatenation.
 
@@ -234,6 +236,7 @@ class DistributedOp:
         into a single MPI_Allreduce)."""
         return self.dotn((a, b), (c, d))
 
+    @in_scope("repro.reduce")
     def sum_partials(self, *vals) -> tuple:
         """Globally reduce already-computed local partial scalars in ONE
         collective — the fused Pallas kernels' dot partials (accumulated

@@ -72,6 +72,7 @@ estimate, like the classics'.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, NamedTuple
 
 import jax
@@ -180,6 +181,19 @@ def _identity(v: jax.Array) -> jax.Array:
     return v
 
 
+def in_scope(name: str) -> Callable:
+    """Decorator: every operation the function traces goes under
+    ``jax.named_scope(name)``.  HLO metadata only, so the compiled program
+    is unchanged; docs/API.md §Observability lists the scope names."""
+    def wrap(fn: Callable) -> Callable:
+        @functools.wraps(fn)
+        def call(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return call
+    return wrap
+
+
 def _stacked_dot(A, dot):
     """The fused-reduction hook of the merged/pipelined variants.
 
@@ -248,17 +262,19 @@ class Ops:
                  params: dict | None = None):
         self.A = A
         self.b = b
-        self.M = M if M is not None else _identity
+        self.M = in_scope("repro.precond")(M) if M is not None else _identity
         own = getattr(A, "dot", None)
-        self.dot = dot if dot is not None else (own or _default_dot)
-        self.dotn = _stacked_dot(A, dot)
+        reduce = in_scope("repro.reduce")
+        self.dot = reduce(dot if dot is not None else (own or _default_dot))
+        self.dotn = reduce(_stacked_dot(A, dot))
         self.params = params or {}
         if norm_ref is None:
             norm_ref = jnp.sqrt(self.dot(b, b))
         self.norm_ref = norm_ref
 
     def matvec(self, x: jax.Array) -> jax.Array:
-        return self.A.matvec(x)
+        with jax.named_scope("repro.matvec"):
+            return self.A.matvec(x)
 
     def dot2(self, a, b, c, d) -> tuple:
         """Two dot products in ONE collective (the paper fuses scalar pairs
@@ -394,6 +410,7 @@ def _status_basic(res2, thresh2):
     return status.astype(jnp.int32)
 
 
+@in_scope("repro.loop")
 def run_method(mdef: MethodDef, ops: Ops, x0: jax.Array, *,
                tol: float = 1e-6, maxiter: int | None = None,
                fused: bool = False, telemetry: int = 0,
@@ -433,6 +450,10 @@ def run_method(mdef: MethodDef, ops: Ops, x0: jax.Array, *,
       every N iterations (methods with ``refresh`` declared — the
       merged/pipelined variants), bounding recurrence drift at a priced
       cost of ``refresh_spmvs`` SpMV-equivalents per refresh.
+
+    Scopes (repro.obs): the driver traces under ``repro.loop``, the
+    method's init under ``repro.init`` and each step under ``repro.step``;
+    ``Ops`` puts its matvec, reductions and preconditioner inside those.
     """
     if maxiter is None:
         maxiter = mdef.default_maxiter
@@ -446,10 +467,11 @@ def run_method(mdef: MethodDef, ops: Ops, x0: jax.Array, *,
             f"refresh_every applies only to methods with one "
             f"(the merged/pipelined variants)")
     init = mdef.fused_init if fused else mdef.init
-    step = mdef.fused_step if fused else mdef.step
+    step = in_scope("repro.step")(mdef.fused_step if fused else mdef.step)
     thresh2 = (tol * ops.norm_ref) ** 2
     ridx = mdef.res_index
-    state = tuple(init(ops, x0))
+    with jax.named_scope("repro.init"):
+        state = tuple(init(ops, x0))
     hist = _hist_init(maxiter, jnp.sqrt(state[ridx]), ops.b.dtype)
 
     if guard_spec is not None or refresh_every:
@@ -1020,7 +1042,7 @@ def _pcg_pipe_fused_step(ops, state):
     x, r, u, w, p, s, q, z, gamma_prev, alpha_prev, rr = state
     gamma, delta, rr_new = ops.A.fused_dots(r, u, w)             # pass 1
     m = ops.M(w)                                   # precond (own kernels)
-    n = ops.A.matvec(m)                                          # SpMV
+    n = ops.matvec(m)                                            # SpMV
     alpha, beta = _cg_merged_scalars(gamma, delta, gamma_prev, alpha_prev)
     x, r, u, w, p, s, q, z = ops.A.ppipe_body(
         alpha, beta, x, r, u, w, p, s, q, z, m, n)               # pass 2

@@ -1,9 +1,10 @@
 # Unified observability (docs/API.md §Observability): structured spans/
-# events on one JSONL schema, per-iteration convergence telemetry, and
-# predicted-vs-measured cost attribution.  Only the zero-dependency trace
-# surface is imported eagerly (span() must stay near-free when disabled);
-# the telemetry/attribution helpers import jax and live in
-# ``repro.obs.convergence`` / ``repro.obs.attribution``.
+# events on one JSONL schema, each span also a profiler annotation; the
+# solve loop's named scopes and their op map (``repro.obs.scopes``); and
+# per-iteration convergence telemetry.  Only the zero-dependency trace
+# surface is imported eagerly (span() must stay near-free when the sink is
+# off); the telemetry helpers import jax and live in
+# ``repro.obs.convergence``.
 from repro.obs.trace import (SCHEMA, Tracer, active, current, disable,
                              emit, enable, event, make_event, make_metric,
                              read_trace, span, summarize, validate_record,

@@ -1,28 +1,19 @@
-"""``python -m repro.obs`` — trace tooling + cost attribution.
+"""``python -m repro.obs`` — trace tooling.
 
 Modes::
 
     python -m repro.obs summarize TRACE.jsonl [--check] [--json]
-    python -m repro.obs attribute --devices 8 --grid 16 16 16 \
-        --methods cg cg_merged cg_pipe [--halo-mode overlap] [--json]
-    python -m repro.obs attribute TRACE.jsonl        # re-render from records
 
 ``summarize`` validates every record against the ``repro.obs/v1`` schema
 and prints the aggregation view (per-span percentiles, event counts);
 ``--check`` exits non-zero on any schema violation — the ``make
-obs-smoke`` CI gate.  ``attribute`` measures the per-phase iteration split
-on a multi-device mesh and prints it against the scaling model's
-prediction (see ``repro.obs.attribution``); given a trace file instead,
-it re-renders the table from the ``obs.attribution`` records a prior run
-emitted.  ``--devices N`` forces N host devices — it must be parsed
-before jax is imported, which is why the heavy imports here are lazy.
+obs-smoke`` CI gate.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 
 
@@ -65,50 +56,10 @@ def _summarize(args) -> int:
     return 0
 
 
-def _attribute(args) -> int:
-    from repro.obs import trace
-    from repro.obs.attribution import (attribution_report, format_table,
-                                       rows_from_trace)
-
-    if args.trace:
-        rows = rows_from_trace(trace.read_trace(args.trace))
-        if not rows:
-            print(f"[obs] {args.trace}: no obs.attribution records",
-                  file=sys.stderr)
-            return 1
-    else:
-        import jax
-
-        from repro.core.problems import enable_f64
-        from repro.launch.mesh import make_solver_mesh
-
-        enable_f64()
-        mesh = make_solver_mesh(min(args.devices, len(jax.devices())))
-        rows = attribution_report(
-            args.methods, tuple(args.grid), mesh, halo_mode=args.halo_mode,
-            inner=args.inner, repeats=args.repeats,
-            profile_dir=args.profile_dir)
-    print(format_table(rows))
-    if args.json:
-        print(json.dumps({"rows": rows}))
-    return 0
-
-
 def main(argv: list[str] | None = None) -> int:
-    argv = sys.argv[1:] if argv is None else list(argv)
-    # --devices pins the host-device count and must precede the jax import;
-    # peek at it before any subcommand work
-    if "attribute" in argv[:1] and "--devices" in argv:
-        n = int(argv[argv.index("--devices") + 1])
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                f"{flags} --xla_force_host_platform_device_count={n}".strip())
-
     ap = argparse.ArgumentParser(
         prog="python -m repro.obs",
-        description="repro.obs trace tooling: schema-checked summaries and "
-                    "predicted-vs-measured cost attribution")
+        description="repro.obs trace tooling: schema-checked summaries")
     sub = ap.add_subparsers(dest="cmd", required=True)
 
     s = sub.add_parser("summarize", help="validate + aggregate a trace")
@@ -117,29 +68,7 @@ def main(argv: list[str] | None = None) -> int:
                    help="exit non-zero on schema violations (the CI gate)")
     s.add_argument("--json", action="store_true")
 
-    a = sub.add_parser("attribute",
-                       help="measure the per-phase iteration split vs the "
-                            "scaling model")
-    a.add_argument("trace", nargs="?", default=None,
-                   help="re-render from a trace's obs.attribution records "
-                        "instead of measuring")
-    a.add_argument("--methods", nargs="+",
-                   default=["cg", "cg_merged", "cg_pipe"])
-    a.add_argument("--grid", type=int, nargs=3, default=[16, 16, 16])
-    a.add_argument("--devices", type=int, default=8,
-                   help="host devices to force (sets XLA_FLAGS; must not "
-                        "already be pinned)")
-    a.add_argument("--halo-mode", default="concat",
-                   choices=["concat", "scatter", "overlap"])
-    a.add_argument("--inner", type=int, default=8,
-                   help="phase trips per timed call (amortises dispatch)")
-    a.add_argument("--repeats", type=int, default=5)
-    a.add_argument("--profile-dir", default=None,
-                   help="also write a jax.profiler trace here")
-    a.add_argument("--json", action="store_true")
-
-    args = ap.parse_args(argv)
-    return _summarize(args) if args.cmd == "summarize" else _attribute(args)
+    return _summarize(ap.parse_args(argv))
 
 
 if __name__ == "__main__":

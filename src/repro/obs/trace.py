@@ -1,9 +1,9 @@
 """Zero-dependency structured tracing: spans, events and metric records.
 
 One schema (``repro.obs/v1``) for every record the repo emits — facade
-solve spans, serve lifecycle events, host heartbeats, attribution
-measurements — persisted as JSON lines so a trace is greppable, appendable
-across processes, and machine-checkable (``validate_stream``).  The
+solve spans, serve lifecycle events, host heartbeats — persisted as JSON
+lines so a trace is greppable, appendable across processes, and
+machine-checkable (``validate_stream``).  The
 aggregations the serving layer reports (p50/p95/p99, QPS) are *views* over
 this stream (:func:`summarize`), not a second bespoke format.
 
@@ -20,7 +20,10 @@ Record kinds
 
 Activation
 ----------
-Disabled by default at near-zero cost (one module-level check per span).
+The JSON-lines sink is disabled by default at near-zero cost (one
+module-level check per span); every span also opens the ``jax.profiler``
+annotation ``repro.<name>`` (a few microseconds), so a profiler trace
+places the program's spans on the device timeline's clock.
 Enable programmatically (``enable(path)`` / ``disable()``) or via the
 ``REPRO_TRACE=PATH`` environment variable (checked lazily on first use;
 ``launch/solve.py --trace`` and ``launch/serve.py --trace`` are the CLI
@@ -38,6 +41,7 @@ import itertools
 import json
 import os
 import platform
+import sys
 import threading
 import time
 
@@ -125,32 +129,46 @@ def _ids() -> dict:
             "host": platform.node()}
 
 
+def _annotation(name: str):
+    """The profiler annotation ``repro.<name>``: a ``jax.profiler``
+    ``TraceAnnotation``, which costs a few microseconds and records only
+    while a profiler trace runs.  Before anything has imported jax no
+    device work exists to line it up with, and this module imports none."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation("repro." + name)
+
+
 @contextlib.contextmanager
 def span(name: str, **attrs):
     """Time a region: ``with span("solve", method="cg"): ...``.
 
-    Yields the span id (``None`` when tracing is disabled — the only cost
-    then is this one check).  The record is emitted on exit, carrying the
-    parent span id of the enclosing ``span`` on this thread.
+    The region always opens the profiler annotation ``repro.<name>``, so a
+    ``jax.profiler`` trace shows it on the host timeline beside the device
+    operations.  Yields the span id, or ``None`` when the JSON-lines sink
+    is disabled.  The record is emitted on exit, carrying the parent span
+    id of the enclosing ``span`` on this thread.
     """
-    tr = current()
-    if tr is None:
-        yield None
-        return
-    sid = tr.next_id()
-    parent = _span_stack.get()
-    token = _span_stack.set(sid)
-    t_wall = time.time()
-    t0 = time.perf_counter()
-    try:
-        yield sid
-    finally:
-        t1 = time.perf_counter()
-        _span_stack.reset(token)
-        tr.emit({"schema": SCHEMA, "kind": "span", "name": name,
-                 "span_id": sid, "parent_id": parent,
-                 "t_start": t0, "t_end": t1, "dur_s": t1 - t0,
-                 "t_wall": t_wall, **_ids(), "attrs": attrs})
+    with _annotation(name):
+        tr = current()
+        if tr is None:
+            yield None
+            return
+        sid = tr.next_id()
+        parent = _span_stack.get()
+        token = _span_stack.set(sid)
+        t_wall = time.time()
+        t0 = time.perf_counter()
+        try:
+            yield sid
+        finally:
+            t1 = time.perf_counter()
+            _span_stack.reset(token)
+            tr.emit({"schema": SCHEMA, "kind": "span", "name": name,
+                     "span_id": sid, "parent_id": parent,
+                     "t_start": t0, "t_end": t1, "dur_s": t1 - t0,
+                     "t_wall": t_wall, **_ids(), "attrs": attrs})
 
 
 def event(name: str, **attrs) -> dict | None:

@@ -1,4 +1,4 @@
-"""repro.obs: tracing, convergence telemetry, cost attribution.
+"""repro.obs: tracing, scopes, convergence telemetry.
 
 The load-bearing contracts:
 
@@ -13,15 +13,18 @@ The load-bearing contracts:
   * the span stream round-trips — records written by an instrumented
     solve validate against the schema and aggregate through the CLI
     summarizer;
-  * attribution's phases sum to ``t_iter`` exactly (t_compute is the raw
-    remainder by construction);
+  * the scope map places each compiled op in the part of the solve that
+    issued it — the stencil in ``repro.matvec``, dots and psums in
+    ``repro.reduce``, ppermutes in ``repro.halo`` — locally and on a mesh;
   * the serve/monitor record unification keeps old readers working —
     pre-PR-8 heartbeat/metrics shapes still parse, and the committed
     PR-6-era ``BENCH_serve.json`` still passes its gate.
 """
 
+import collections
 import json
 import os
+import re
 import time
 
 import numpy as np
@@ -363,53 +366,132 @@ def test_trajectory_rows_append(tmp_path):
 
 
 # -----------------------------------------------------------------------------
-# attribution: phases sum to t_iter; rows flow through the trace (slow)
+# scopes: which compiled op belongs to which part of the solve
 # -----------------------------------------------------------------------------
 
-_ATTRIB_SCRIPT = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-os.environ["REPRO_TRACE"] = os.environ["ATTRIB_TRACE"]
-import json
+def _fusions(hlo_text: str) -> dict[str, collections.Counter]:
+    """``{fusion instruction: Counter of its fused opcodes}``."""
+    comps: dict[str, collections.Counter] = {}
+    comp = None
+    for line in hlo_text.splitlines():
+        m = re.match(r"^%([\w.\-]+) .*\{\s*$", line)
+        if m:
+            comp = comps.setdefault(m.group(1), collections.Counter())
+            continue
+        m = re.match(r"^\s*(?:ROOT\s+)?%[\w.\-]+ = .*? ([a-z\-]+)\(", line)
+        if m and comp is not None:
+            comp[m.group(1)] += 1
+    out = {}
+    for m in re.finditer(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = .*kind=k\w+, "
+                         r"calls=%([\w.\-]+)", hlo_text, re.M):
+        out[m.group(1)] = comps[m.group(2)]
+    return out
+
+
+def test_op_scopes_of_a_local_cg_session():
+    sess = SolverSession(make_problem((16, 16, 16), "27pt"), method="cg",
+                         options=SolverOptions(tol=1e-8, maxiter=300))
+    assert sess.op_scopes() == {}            # nothing compiled yet
+    sess.solve()
+    scopes = sess.op_scopes()
+    (ex,) = sess._executables.values()
+    text = ex.as_text()
+    module = re.match(r"HloModule ([^\s,]+)", text).group(1)
+    assert {m for m, _ in scopes} == {module}
+    by_op = {op: sc for (_, op), sc in scopes.items()}
+    # the 27-point stencil: one fusion of 26 shifted slices per apply
+    # (r0 = b - A x0 in init, q = A p in each step), placed by most of its
+    # fused instructions even where another scope's op is its root
+    stencils = [f for f, ops in _fusions(text).items() if ops["slice"] >= 26]
+    assert len(stencils) == 2
+    assert all(by_op[f] == "repro.matvec" for f in stencils)
+    dots = re.findall(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = \S+ dot\(", text, re.M)
+    assert dots and all(by_op[d] == "repro.reduce" for d in dots)
+    assert set(scopes.values()) == {"repro.loop", "repro.init", "repro.step",
+                                    "repro.matvec", "repro.reduce"}
+    whiles = re.findall(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = .* while\(", text,
+                        re.M)
+    assert whiles and all(by_op[w] == "repro.loop" for w in whiles)
+
+
+@pytest.mark.parametrize("op_name,scope", [
+    ("jit(run)/repro.loop/while/body/repro.step/repro.matvec/add",
+     "repro.matvec"),
+    ("jit(fn)/shard_map/repro.loop/while/body/repro.step/repro.matvec/"
+     "repro.halo/ppermute", "repro.halo"),
+    ("jit(run)/repro.loop/while/cond/lt", "repro.loop"),
+    ("jit(run)/sqrt", None),
+    ("jit(run)/repro.loop/repro.unknown/add", "repro.loop"),
+])
+def test_scope_of_takes_the_innermost_repro_scope(op_name, scope):
+    from repro.obs.scopes import scope_of
+    assert scope_of(op_name) == scope
+
+
+def test_op_scopes_keys_ops_by_module():
+    from repro.obs.scopes import op_scopes_from_text
+    text = "\n".join([
+        "HloModule jit_a, entry_computation_layout={()->f32[]}",
+        "ENTRY %main.1 () -> f32[] {",
+        '  ROOT %add.1 = f32[] add(%x, %y), metadata={op_name="jit(a)/'
+        'repro.loop/while/body/repro.step/add"}',
+        "}",
+        "HloModule jit_b, entry_computation_layout={()->f32[]}",
+        "ENTRY %main.1 () -> f32[] {",
+        '  ROOT %add.1 = f32[] add(%x, %y), metadata={op_name="jit(b)/'
+        'repro.reduce/add"}',
+        "}",
+    ])
+    assert op_scopes_from_text(text) == {("jit_a", "add.1"): "repro.step",
+                                         ("jit_b", "add.1"): "repro.reduce"}
+
+
+_SCOPES_SCRIPT = r"""
+import json, re
 import jax
 jax.config.update("jax_enable_x64", True)
+from jax.sharding import Mesh
+import numpy as np
+from repro.api import SolverOptions, SolverSession
 from repro.core.problems import make_problem
-from repro.launch.mesh import make_solver_mesh
-from repro.obs.attribution import format_table, measure_phase_split
 
-prob = make_problem((16, 16, 16), "27pt")
-mesh = make_solver_mesh(8)
-rows = [measure_phase_split(prob, m, mesh, inner=2, repeats=2)
-        for m in ("cg", "cg_merged")]
-table = format_table(rows)
-print(json.dumps({"rows": rows, "table_lines": len(table.splitlines())}))
+mesh = Mesh(np.array(jax.devices()[:4]), ("cells",))
+out = {}
+for method in ("cg", "cg_merged"):
+    for mode in ("concat", "scatter", "overlap"):
+        sess = SolverSession(make_problem((8, 8, 16), "27pt"), method=method,
+                             options=SolverOptions(tol=1e-8, maxiter=200,
+                                                   layout="1d",
+                                                   halo_mode=mode),
+                             mesh=mesh)
+        res = sess.solve()
+        (ex,) = sess._executables.values()
+        text = ex.as_text()
+        scopes = {op: sc for (_, op), sc in sess.op_scopes().items()}
+        kinds = {}
+        for m in re.finditer(
+                r"^\s*(?:ROOT\s+)?%([\w.\-]+) = .*? "
+                r"(collective-permute|all-reduce)(?:-start)?\(", text, re.M):
+            kinds.setdefault(m.group(2), []).append(scopes.get(m.group(1)))
+        out[f"{method}/{mode}"] = {"kinds": kinds, "iters": int(res.iters)}
+print(json.dumps(out))
 """
 
 
-@pytest.mark.slow
-def test_attribution_sums_and_traces(tmp_path):
-    trace_path = str(tmp_path / "attrib.jsonl")
-    out = run_multidevice(_ATTRIB_SCRIPT,
-                          env={"ATTRIB_TRACE": trace_path})
-    assert out["table_lines"] == 2 + len(out["rows"])
-    for row in out["rows"]:
-        m = row["measured"]
-        # t_compute is the raw remainder: the split sums exactly
-        assert m["t_iter"] == pytest.approx(
-            m["t_halo"] + m["t_reduce"] + m["t_compute"], abs=1e-12)
-        assert m["t_iter"] > 0 and m["t_halo"] > 0 and m["t_reduce"] > 0
-        for k in ("t_mem", "t_halo", "t_precond", "t_reduce", "total"):
-            assert k in row["predicted"], k
-        assert row["mesh"]["devices"] == 8
-    # cg_merged declares half cg's allreduces — attribution must price that
-    by = {r["method"]: r for r in out["rows"]}
-    assert (by["cg_merged"]["counts"]["allreduces"]
-            < by["cg"]["counts"]["allreduces"])
-    # every emitted record validates; the rows round-trip from the trace
-    assert obs.validate_stream(trace_path) == []
-    from repro.obs.attribution import rows_from_trace
-    rt = rows_from_trace(obs.read_trace(trace_path))
-    assert [r["method"] for r in rt] == ["cg", "cg_merged"]
+@pytest.fixture(scope="module")
+def sharded_scopes():
+    return run_multidevice(_SCOPES_SCRIPT, devices=4)
+
+
+@pytest.mark.parametrize("case", [f"{m}/{h}" for m in ("cg", "cg_merged")
+                                  for h in ("concat", "scatter", "overlap")])
+def test_sharded_collectives_land_in_halo_and_reduce(sharded_scopes, case):
+    got = sharded_scopes[case]
+    assert got["iters"] > 0
+    permutes = got["kinds"]["collective-permute"]
+    reduces = got["kinds"]["all-reduce"]
+    assert permutes and set(permutes) == {"repro.halo"}
+    assert reduces and set(reduces) == {"repro.reduce"}
 
 
 def test_iteration_breakdown_is_iteration_time():
