@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.methods import Ops, get_method, in_scope, run_method
+from repro.core.methods import Ops, get_method, in_scope, local_dot, run_method
 from repro.core.operators import Stencil, interior_matvec, shell_assemble
 from repro.core.problems import HPCGProblem
 from repro.core.solvers import SolveResult
@@ -218,7 +218,7 @@ class DistributedOp:
     def dot(self, a: jax.Array, b: jax.Array) -> jax.Array:
         # single psum over the tuple of axes == ONE all-reduce (one barrier),
         # exactly like one MPI_Allreduce over the world communicator.
-        return lax.psum(jnp.vdot(a, b), self.layout.reduce_axes)
+        return lax.psum(local_dot(a, b), self.layout.reduce_axes)
 
     def dotn(self, *pairs) -> tuple:
         """Any number of dot products in ONE collective: stack the local
@@ -227,7 +227,7 @@ class DistributedOp:
         dots) through this — one all-reduce per iteration, verified on the
         compiled HLO by tests/test_hlo_analysis.py."""
         stacked = lax.psum(
-            jnp.stack([jnp.vdot(a, b) for a, b in pairs]),
+            jnp.stack([local_dot(a, b) for a, b in pairs]),
             self.layout.reduce_axes)
         return tuple(stacked[i] for i in range(len(pairs)))
 

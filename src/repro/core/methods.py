@@ -30,9 +30,10 @@ across methods and backends.
 the ``LocalOp`` protocol — ``matvec``/``pad_exchange``/``diag``/``dotn``),
 the right-hand side ``b``, the bound preconditioner apply ``M`` (identity
 when absent), and the reduction hooks ``dot``/``dot2``/``dotn``.  On a
-single device the reductions are plain ``jnp.vdot``; inside ``shard_map``
-they are the layout's ``psum`` — the method definition cannot tell, which is
-the whole point (the paper's write-once/parallelise-underneath rule).
+single device the reductions are :func:`local_dot` (a multiply and a sum);
+inside ``shard_map`` they are its local partials under the layout's
+``psum`` — the method definition cannot tell, which is the whole point (the
+paper's write-once/parallelise-underneath rule).
 
 Barrier structure reproduced from the paper (§3.1, Fig. 1):
 
@@ -173,8 +174,18 @@ class SolveResult(NamedTuple):
     status: jax.Array | None = None
 
 
-def _default_dot(a: jax.Array, b: jax.Array) -> jax.Array:
-    return jnp.vdot(a, b)
+def local_dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    """The local dot product of every solver reduction: an elementwise
+    product summed in the operands' dtype.
+
+    The operators are real and symmetric, so there is no conjugation to
+    keep.  Not ``jnp.vdot``: a float64 ``dot_general`` on a TPU takes XLA's
+    multi-limb dot emulation (``while`` loops over f32 pieces staged in
+    vector-sized buffers), where a float64 multiply and ``reduce`` take the
+    same float64 emulation as the vector updates.  A float32 vector
+    ``dot_general`` already compiles to this multiply-reduce.
+    """
+    return jnp.sum(a * b)
 
 
 def _identity(v: jax.Array) -> jax.Array:
@@ -208,7 +219,7 @@ def _stacked_dot(A, dot):
         dn = getattr(A, "dotn", None)
         if dn is not None:
             return dn
-    d = dot or _default_dot
+    d = dot or local_dot
 
     def dotn(*pairs):
         return tuple(d(a, b) for a, b in pairs)
@@ -250,8 +261,8 @@ class Ops:
     Bundles the operator, the right-hand side, the bound preconditioner
     apply and the reduction hooks.  ``dot`` defaults to the operator's own
     global reduction (``DistributedOp.dot`` = one psum) when it has one,
-    else ``jnp.vdot``; ``dotn`` stacks any number of dot products into ONE
-    collective where the operator supports it (see :func:`_stacked_dot`).
+    else :func:`local_dot`; ``dotn`` stacks any number of dot products into
+    ONE collective where the operator supports it (see :func:`_stacked_dot`).
     ``norm_ref=None`` resolves to ``||b||`` via ``dot`` (the relative
     criterion); the paper's absolute HPCCG criterion is ``norm_ref=1.0``.
     """
@@ -265,7 +276,7 @@ class Ops:
         self.M = in_scope("repro.precond")(M) if M is not None else _identity
         own = getattr(A, "dot", None)
         reduce = in_scope("repro.reduce")
-        self.dot = reduce(dot if dot is not None else (own or _default_dot))
+        self.dot = reduce(dot if dot is not None else (own or local_dot))
         self.dotn = reduce(_stacked_dot(A, dot))
         self.params = params or {}
         if norm_ref is None:

@@ -39,12 +39,12 @@ from repro.core.methods import (  # noqa: F401  (compat re-exports)
     SolveResult,
     _cg_merged_scalars,
     _colour_mask,
-    _default_dot,
     _hist_init,
     _plane_sweep,
     _rb_half_sweep,
     _stacked_dot,
     get_method,
+    local_dot,
     run_method,
 )
 from repro.core.operators import Stencil
@@ -75,7 +75,7 @@ class LocalOp:
     def dotn(self, *pairs) -> tuple:
         """Stacked dot products — locally just the dots (no collective to
         fuse); ``DistributedOp.dotn`` is the one-psum version."""
-        return tuple(jnp.vdot(a, b) for a, b in pairs)
+        return tuple(local_dot(a, b) for a, b in pairs)
 
     def sum_partials(self, *vals) -> tuple:
         """Reduce already-computed local partial scalars globally — locally

@@ -41,6 +41,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.methods import local_dot
 from repro.kernels import autotune, ops
 
 
@@ -85,7 +86,7 @@ class PallasOp:
     @property
     def dot(self):
         d = getattr(self.base, "dot", None)
-        return d if d is not None else jnp.vdot
+        return d if d is not None else local_dot
 
     def dotn(self, *pairs) -> tuple:
         return self.base.dotn(*pairs)

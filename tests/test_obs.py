@@ -405,7 +405,8 @@ def test_op_scopes_of_a_local_cg_session():
     stencils = [f for f, ops in _fusions(text).items() if ops["slice"] >= 26]
     assert len(stencils) == 2
     assert all(by_op[f] == "repro.matvec" for f in stencils)
-    dots = re.findall(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = \S+ dot\(", text, re.M)
+    # the dot products (a multiply and a sum): each a fusion of its own
+    dots = [f for f, ops in _fusions(text).items() if ops["reduce"]]
     assert dots and all(by_op[d] == "repro.reduce" for d in dots)
     assert set(scopes.values()) == {"repro.loop", "repro.init", "repro.step",
                                     "repro.matvec", "repro.reduce"}

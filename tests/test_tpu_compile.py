@@ -10,6 +10,8 @@ only one process at a time may load the TPU library, and every test worker
 imports this file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -86,10 +88,16 @@ def test_xla_cg_loop_compiles_in_f64_for_v5e(one_chip):
     b = _arg((N,) * 3, jnp.float64, one_chip)
     compiled = jax.jit(
         lambda b, x0: cg(op, b, x0, tol=1e-6, maxiter=600)).lower(b, b).compile()
-    assert "tpu_custom_call" not in compiled.as_text()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" not in hlo
+    # one while: the solve loop.  A float64 dot_general would add XLA's
+    # multi-limb dot emulation loops (17 more in this loop)
+    assert len(re.findall(r"\swhile\(", hlo)) == 1
     mem = compiled.memory_analysis()
     # x, r, p, Ap and b at 128³ in f64 fit a 16 GB chip many times over
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 2e9
+    # and the dots stage no vector-sized f32 pieces in temporaries
+    assert mem.temp_size_in_bytes < 100e6
 
 
 def test_f64_pallas_request_raises_before_compiling(one_chip, monkeypatch):
