@@ -340,7 +340,8 @@ class SolverSession:
                       backend=self.backend.kind):
             with obs.span("inputs"):
                 b = self.problem.b() if b is None else b
-                x0 = self.problem.x0() if x0 is None else x0
+                if x0 is None:
+                    x0 = self.problem.x0(self.backend.sharding())
                 fn = self._executable(
                     tuple(self.problem.shape), self._build_fn,
                     (self._abstract(tuple(self.problem.shape)),) * 2)
@@ -454,7 +455,8 @@ class SolverSession:
                       backend=self.backend.kind, timed=True,
                       repeats=repeats):
             b = self._place(self.problem.b() if b is None else b)
-            x0 = self._place(self.problem.x0() if x0 is None else x0)
+            x0 = self._place(self.problem.x0(self.backend.sharding())
+                             if x0 is None else x0)
             if self._timed_fn is None:
                 # the jit is lazy, so AOT-lower here to give the compile its
                 # own honest span (warm-up inside timed_result would

@@ -84,13 +84,9 @@ class DistributedOp:
                  halo_mode: str = "auto"):
         self.stencil = stencil
         self.layout = layout
-        # measured per-stencil bests (EXPERIMENTS.md §Perf): the slice-add
-        # stencil fuses well at 7pt; the conv formulation halves traffic at
-        # 27pt; concat halos beat pad+scatter in both conv cases
-        if matvec_padded is None:
-            matvec_padded = (stencil.conv_matvec_padded()
-                             if stencil.npoint >= 27 else stencil.matvec_padded)
-        self._mv_padded = matvec_padded
+        # the slice-add apply LocalOp runs, so one block's arithmetic is the
+        # same on one chip and on a mesh
+        self._mv_padded = matvec_padded or stencil.matvec_padded
         if halo_mode not in HALO_MODES:
             raise ValueError(
                 f"unknown halo_mode {halo_mode!r}; options: {HALO_MODES}")
@@ -151,30 +147,29 @@ class DistributedOp:
     def _pad_exchange_concat(self, x: jax.Array) -> jax.Array:
         """Optimised: build the padded array by per-dim concatenation.
 
-        The received (or zero) halo planes are concatenated onto the block
-        dim by dim — one materialisation per dim instead of pad + scatter
-        pairs, and XLA folds the nested concats into a single copy.  Later
-        dims' slabs span the already-extended extents, so 27-pt corner
-        neighbours arrive exactly as in the scatter form.
+        Unsplit dims take their zero halo from one ``jnp.pad``, as
+        ``Stencil.matvec`` does; the received planes of each split dim are
+        then concatenated onto the block — no pad + scatter pairs, and XLA
+        folds the nested concats into a single copy.  Later dims' slabs
+        span the already-extended extents, so 27-pt corner neighbours
+        arrive exactly as in the scatter form.  (Zero planes concatenated
+        onto the unsplit dims gave the same values, but XLA's CPU backend
+        rounded the 7-point apply of that operand differently from the
+        global one, by up to 7.6e-6 on N(0, 1) data.)
         """
-        xp = x
-        for d in range(3):
+        split = self.split_dims
+        xp = jnp.pad(x, [(0, 0) if d in split else (1, 1) for d in range(3)])
+        for d in split:
             axis = self.layout.dim_axes[d]
-            shape = list(xp.shape)
-            shape[d] = 1
-            zero = jnp.zeros(shape, xp.dtype)
-            n = self.layout.mesh.shape[axis] if axis is not None else 1
-            if axis is None or n == 1:
-                lo = hi = zero
-            else:
-                sl_lo = [slice(None)] * 3
-                sl_hi = [slice(None)] * 3
-                sl_lo[d] = slice(0, 1)
-                sl_hi[d] = slice(xp.shape[d] - 1, xp.shape[d])
-                lo = lax.ppermute(xp[tuple(sl_hi)], axis,
-                                  [(i, i + 1) for i in range(n - 1)])
-                hi = lax.ppermute(xp[tuple(sl_lo)], axis,
-                                  [(i + 1, i) for i in range(n - 1)])
+            n = self.layout.mesh.shape[axis]
+            sl_lo = [slice(None)] * 3
+            sl_hi = [slice(None)] * 3
+            sl_lo[d] = slice(0, 1)
+            sl_hi[d] = slice(xp.shape[d] - 1, xp.shape[d])
+            lo = lax.ppermute(xp[tuple(sl_hi)], axis,
+                              [(i, i + 1) for i in range(n - 1)])
+            hi = lax.ppermute(xp[tuple(sl_lo)], axis,
+                              [(i + 1, i) for i in range(n - 1)])
             xp = jnp.concatenate([lo, xp, hi], axis=d)
         return xp
 

@@ -85,29 +85,6 @@ class Stencil:
         """Apply to an unpadded grid array ``(nx, ny, nz)`` with zero boundary."""
         return self.matvec_padded(jnp.pad(x, 1))
 
-    def conv_matvec_padded(self):
-        """Matrix-free stencil apply as a 3x3x3 convolution.
-
-        One streaming pass over the padded input: measured 47.3 -> 23.5
-        r-units of HBM traffic per CG iteration at the 27-pt stencil
-        (EXPERIMENTS.md §Perf) — matrix-FREE beats the paper's CSR
-        accounting because the constant coefficients live in the kernel,
-        eliminating the (n̄+1)·r matrix-value reads entirely.  The stencil
-        is symmetric, so cross-correlation == convolution.
-        """
-        k = np.zeros((3, 3, 3), np.float64)
-        k[1, 1, 1] = self.diag
-        for dx, dy, dz in self.offsets:
-            k[1 + dx, 1 + dy, 1 + dz] = self.off_coeff
-
-        def mv(xp: jax.Array) -> jax.Array:
-            kern = jnp.asarray(k, xp.dtype)[None, None]  # (O=1, I=1, 3, 3, 3)
-            x4 = xp[None, None]                          # (N=1, C=1, X, Y, Z)
-            y = jax.lax.conv_general_dilated(x4, kern, (1, 1, 1), "VALID")
-            return y[0, 0]
-
-        return mv
-
     # --- Gauss-Seidel helpers -------------------------------------------------
     def offdiag_apply_padded(self, xp: jax.Array) -> jax.Array:
         """(A - D) x on a padded array."""
@@ -143,8 +120,8 @@ class Stencil:
 # decomposed face read no exchanged halo, so they can be computed while the
 # ppermutes are in flight; only the one-cell-thick boundary shell waits for
 # the received planes.  Both functions delegate the actual apply to a
-# ``matvec_padded`` callable, so the slice-add, conv and Pallas formulations
-# all split the same way.  Each output element's arithmetic is
+# ``matvec_padded`` callable, so the slice-add and Pallas formulations
+# split the same way.  Each output element's arithmetic is
 # position-independent, so the split reproduces the monolithic apply exactly
 # up to the compiler's per-shape FMA contraction choices; in the solver
 # programs the results are bit-for-bit identical across halo modes

@@ -44,8 +44,10 @@ class HPCGProblem:
         """RHS for x* = 1: b = A @ ones (zero in the interior for HPCG-27)."""
         return _rhs(self.stencil, self.shape, self.dtype)
 
-    def x0(self) -> jax.Array:
-        return jnp.zeros(self.shape, self.dtype)
+    def x0(self, sharding=None) -> jax.Array:
+        """The zero initial guess, made where ``sharding`` places it: on a
+        mesh each chip writes its own block, no whole grid on one chip."""
+        return jnp.zeros(self.shape, self.dtype, device=sharding)
 
     def x_true(self) -> jax.Array:
         return jnp.ones(self.shape, self.dtype)

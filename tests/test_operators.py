@@ -82,17 +82,16 @@ def test_touched_elements_paper_table():
 
 @pytest.mark.parametrize("stencil", [STENCIL_7PT, STENCIL_27PT],
                          ids=lambda s: s.name)
-@pytest.mark.parametrize("formulation", ["slice", "conv"])
+@pytest.mark.parametrize("formulation", ["slice"])
 @pytest.mark.parametrize("split_dims", [(2,), (0, 2), (0, 1, 2)])
 def test_interior_shell_split_matches_monolithic(stencil, formulation,
                                                  split_dims):
     """The overlapped-SpMV decomposition: interior apply on the raw block +
     shell slabs from the padded array must reassemble to exactly the
-    monolithic apply, for both stencil formulations and any split set."""
+    monolithic apply (the slice-add formulation), for any split set."""
     from repro.core.operators import interior_matvec, shell_assemble
 
-    mv = (stencil.conv_matvec_padded() if formulation == "conv"
-          else stencil.matvec_padded)
+    mv = stencil.matvec_padded
     x = jax.random.normal(jax.random.PRNGKey(1), (6, 8, 10), jnp.float32)
     # an arbitrary "exchanged" padded array: random halos on split dims
     xp = jax.random.normal(jax.random.PRNGKey(2), (8, 10, 12), jnp.float32)

@@ -29,7 +29,8 @@ N = 128
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
+    """The four devices of a described v5e 2x2 host."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     try:
@@ -45,9 +46,14 @@ def one_chip():
     compilation_cache.reset_cache()
     # the solvers run with x64 on; the kernels must compile under it
     jax.config.update("jax_enable_x64", True)
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_x64", prev_x64)
     jax.config.update("jax_enable_compilation_cache", prev_cache)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2[0])
 
 
 def _arg(shape, dtype, sharding):
@@ -110,3 +116,36 @@ def test_f64_pallas_request_raises_before_compiling(one_chip, monkeypatch):
     # float32 is accepted
     SolverSession(method="cg_merged", grid=(N,) * 3, stencil="27pt",
                   options=SolverOptions(pallas=True, f64=False))
+
+
+def test_four_chip_cg_at_512_per_chip_compiles_for_v5e_2x2(v5e_2x2):
+    """The benchmark's four-chip cell, built as the benchmark builds it:
+    512³ float32 per chip, 512 x 512 x 2048 split along z.  Its stencil is
+    the slice-add apply; a 3-D convolution there staged one 68.7 GB
+    intermediate, four times a chip's memory."""
+    import json
+    import pathlib
+
+    from bench import harness
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    config = json.loads(
+        (root / "bench/configs/hpcg27-cg-f32-b512-x4.json").read_text())
+    jax.config.update("jax_enable_x64", False)   # as the harness runs float32
+    try:
+        sess = harness.build_session(config, list(v5e_2x2))
+        assert sess.problem.shape == (512, 512, 2048)
+        assert sess.halo_mode == "overlap"
+        arg = jax.ShapeDtypeStruct(sess.problem.shape, sess.problem.dtype,
+                                   sharding=sess.backend.sharding())
+        compiled = sess._build_fn().lower(arg, arg).compile()
+    finally:
+        jax.config.update("jax_enable_x64", True)
+    hlo = compiled.as_text()
+    assert "convolution" not in hlo
+    assert "collective-permute" in hlo and "all-reduce" in hlo
+    mem = compiled.memory_analysis()
+    # per chip: b and x0 (1.07 GB) in arguments; x, r, p, q and the
+    # padded operand's pieces in temporaries
+    assert mem.argument_size_in_bytes == 2 * 4 * 512 ** 3
+    assert mem.temp_size_in_bytes < 6e9
