@@ -249,6 +249,17 @@ _TRIVIAL_OPS = {
     "transpose", "copy-start", "copy-done", "after-all", "partition-id",
 }
 
+# XLA's CPU backend wraps a lone op in a fusion called ``wrapped_<op>`` even
+# with its fusion passes off (``wrapped_broadcast.1``)
+_WRAPPED_RE = re.compile(r"wrapped_([a-z_\-]+?)(?:\.\d+)?$")
+
+
+def _work_opcode(ins) -> str:
+    """The opcode whose work ``ins`` does: the wrapped op's, for a
+    single-op wrapper fusion."""
+    m = _WRAPPED_RE.match(ins.name) if ins.opcode == "fusion" else None
+    return m.group(1).replace("_", "-") if m else ins.opcode
+
 
 def overlap_slack(hlo_text: str, computation_filter: str | None = None,
                   ops: tuple[str, ...] | None = None):
@@ -283,7 +294,8 @@ def overlap_slack(hlo_text: str, computation_filter: str | None = None,
                     bwd[i].append(j)
         weights = np.array(
             [
-                0.0 if ins.opcode in _TRIVIAL_OPS else float(ins.result_bytes)
+                0.0 if _work_opcode(ins) in _TRIVIAL_OPS
+                else float(ins.result_bytes)
                 for ins in comp.instructions
             ]
         )

@@ -176,7 +176,10 @@ class DistributedOp:
     def matvec(self, x: jax.Array) -> jax.Array:
         if self.halo_mode == "overlap":
             return self._matvec_overlap(x)
-        return self._mv_padded(self.pad_exchange(x))
+        # fork-join: the barrier keeps XLA from taking the slices that lie
+        # inside the block from ``x`` instead of the padded operand, so the
+        # whole apply waits for every received plane
+        return self._mv_padded(lax.optimization_barrier(self.pad_exchange(x)))
 
     def matvec_local(self, x: jax.Array) -> jax.Array:
         """Zero-halo apply on the local block ONLY — no ppermutes.

@@ -454,8 +454,10 @@ jax.config.update("jax_enable_x64", True)
 from jax.sharding import Mesh
 import numpy as np
 from repro.api import SolverOptions, SolverSession
+from repro.analysis.hlo import parse_computations
 from repro.core.problems import make_problem
 
+ARITH = ("add", "subtract", "multiply")
 mesh = Mesh(np.array(jax.devices()[:4]), ("cells",))
 out = {}
 for method in ("cg", "cg_merged"):
@@ -474,7 +476,25 @@ for method in ("cg", "cg_merged"):
                 r"^\s*(?:ROOT\s+)?%([\w.\-]+) = .*? "
                 r"(collective-permute|all-reduce)(?:-start)?\(", text, re.M):
             kinds.setdefault(m.group(2), []).append(scopes.get(m.group(1)))
-        out[f"{method}/{mode}"] = {"kinds": kinds, "iters": int(res.iters)}
+        comps = {c.name: c for c in parse_computations(text)}
+
+        def arith(ins):
+            if ins.opcode == "fusion":
+                called = re.search(r"calls=%([\w.\-]+)", ins.raw).group(1)
+                return any(i.opcode in ARITH
+                           for i in comps[called].instructions)
+            return ins.opcode in ARITH
+
+        halo_arith, matvec_arith = [], 0
+        for comp in comps.values():
+            for ins in comp.instructions:
+                if scopes.get(ins.name) == "repro.halo" and arith(ins):
+                    halo_arith.append(ins.name)
+                elif scopes.get(ins.name) == "repro.matvec" and arith(ins):
+                    matvec_arith += 1
+        out[f"{method}/{mode}"] = {"kinds": kinds, "iters": int(res.iters),
+                                   "halo_arith": halo_arith,
+                                   "matvec_arith": matvec_arith}
 print(json.dumps(out))
 """
 
@@ -487,12 +507,17 @@ def sharded_scopes():
 @pytest.mark.parametrize("case", [f"{m}/{h}" for m in ("cg", "cg_merged")
                                   for h in ("concat", "scatter", "overlap")])
 def test_sharded_collectives_land_in_halo_and_reduce(sharded_scopes, case):
+    """Every halo permute in ``repro.halo``, every all-reduce in
+    ``repro.reduce``, and the stencil arithmetic of the interior and the
+    shell slabs in ``repro.matvec``, none of it in ``repro.halo``."""
     got = sharded_scopes[case]
     assert got["iters"] > 0
     permutes = got["kinds"]["collective-permute"]
     reduces = got["kinds"]["all-reduce"]
     assert permutes and set(permutes) == {"repro.halo"}
     assert reduces and set(reduces) == {"repro.reduce"}
+    assert got["halo_arith"] == []
+    assert got["matvec_arith"] > 0
 
 
 def test_iteration_breakdown_is_iteration_time():

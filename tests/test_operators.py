@@ -82,10 +82,8 @@ def test_touched_elements_paper_table():
 
 @pytest.mark.parametrize("stencil", [STENCIL_7PT, STENCIL_27PT],
                          ids=lambda s: s.name)
-@pytest.mark.parametrize("formulation", ["slice"])
 @pytest.mark.parametrize("split_dims", [(2,), (0, 2), (0, 1, 2)])
-def test_interior_shell_split_matches_monolithic(stencil, formulation,
-                                                 split_dims):
+def test_interior_shell_split_matches_monolithic(stencil, split_dims):
     """The overlapped-SpMV decomposition: interior apply on the raw block +
     shell slabs from the padded array must reassemble to exactly the
     monolithic apply (the slice-add formulation), for any split set."""
