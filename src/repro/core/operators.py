@@ -11,6 +11,13 @@ zero-padded array.  Zero halos reproduce the HPCG boundary treatment exactly
 because the matrix keeps a constant diagonal and simply drops out-of-domain
 neighbours (``-1 * 0 == dropped``).
 
+The 27-point operator has one coefficient for all 26 neighbours, so its
+apply is ``(diag - off) * x + off * box(x)`` with ``box`` the 3x3x3 sum
+including the centre.  The box sum separates into three 3-point sums, one
+per axis: 6 adds in place of 26, and each shift lies along one dim only
+(XLA tiles the two minor dims, where a shift costs a relayout).  The 7-point
+cross gains nothing from separating and keeps one shifted add per neighbour.
+
 An ELLPACK path (`ELLOperator`) is retained for generality (any bounded-row
 sparse matrix) and doubles as the cross-check oracle for the stencil path.
 """
@@ -44,13 +51,33 @@ def _offsets_27pt() -> tuple[tuple[int, int, int], ...]:
     return tuple(offs)
 
 
+def _box_sum(xp: jax.Array) -> jax.Array:
+    """The 3x3x3 sum of a padded array, ``(nx+2, ny+2, nz+2)`` ->
+    ``(nx, ny, nz)``: a 3-point sum along z, then y, then x.
+
+    The order is fixed, so every output element takes the same additions
+    wherever it lies (the interior/shell split stays exact).  z goes first
+    because it is the lane dim of the one-chip layout, where a shift costs
+    most; each sum is one pass over a partial that XLA keeps.
+    """
+    for axis in (2, 1, 0):
+        n = xp.shape[axis] - 2
+        xp = (jax.lax.slice_in_dim(xp, 0, n, axis=axis)
+              + jax.lax.slice_in_dim(xp, 1, n + 1, axis=axis)
+              + jax.lax.slice_in_dim(xp, 2, n + 2, axis=axis))
+    return xp
+
+
 @jax.tree_util.register_static
 @dataclasses.dataclass(frozen=True)
 class Stencil:
     """Constant-coefficient centred stencil operator on a 3-D grid.
 
     ``A x`` for row (i,j,k):  ``diag * x[i,j,k] + off_coeff * sum(neigh x)``
-    with out-of-domain neighbours dropped (== zero-padded halo).
+    with out-of-domain neighbours dropped (== zero-padded halo).  When the
+    offsets are the whole 26-neighbour box (``is_box``), ``matvec_padded``
+    applies it as the separable box sum; any other offset set is applied
+    one shifted add per neighbour.
     """
 
     name: str
@@ -67,12 +94,28 @@ class Stencil:
         """Average nonzeros per row (paper's n̄): 7 or 27 for interior rows."""
         return self.npoint
 
+    @property
+    def is_box(self) -> bool:
+        """Whether the offsets are exactly the 26 neighbours of the 3x3x3
+        box, each once (in any order): the off-diagonal part is then
+        ``off_coeff * (box(x) - x)``."""
+        return (len(self.offsets) == 26
+                and set(self.offsets) == set(_offsets_27pt()))
+
     def matvec_padded(self, xp: jax.Array) -> jax.Array:
         """Apply to a halo-padded array ``(nx+2, ny+2, nz+2)`` -> ``(nx, ny, nz)``.
 
         This is the pure-jnp oracle; kernels/stencil_spmv.py is the Pallas
-        version with explicit VMEM tiling.
+        version with explicit VMEM tiling.  A box stencil is applied as
+        ``(diag - off_coeff) * x + off_coeff * box(x)``.  The barrier makes
+        the result one array written by the apply: without it XLA recomputes
+        the last axis sum and the combine inside each consumer of the
+        result (CG's r update), outside the apply's pass.
         """
+        if self.is_box:
+            q = ((self.diag - self.off_coeff) * xp[1:-1, 1:-1, 1:-1]
+                 + self.off_coeff * _box_sum(xp))
+            return jax.lax.optimization_barrier(q)
         nx, ny, nz = xp.shape[0] - 2, xp.shape[1] - 2, xp.shape[2] - 2
         acc = self.diag * xp[1:-1, 1:-1, 1:-1]
         for dx, dy, dz in self.offsets:

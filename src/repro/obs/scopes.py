@@ -6,9 +6,9 @@ keeps the scope path in each instruction's ``op_name`` metadata, so the
 text of a compiled executable says which ``repro.*`` scope each device
 operation of a profiler trace ran in.
 
-A fusion's own metadata is its root's, and a multi-output root is often
-the cheap part: the float32 CG stencil fusion (26 shifted slices and adds
-of ``q = A p`` with ``p·q`` fused in) carries the dot's ``repro.reduce``.
+A fusion's own metadata is its root's, and the root is often the cheap
+part: a stencil fusion of ``q = A p`` with ``p·q`` fused in carries the
+dot's ``repro.reduce``, and one of ``r = b - A x`` the subtract's scope.
 So a fusion is placed in the scope that holds most of its fused
 instructions, and in its own scope on a tie.  The map is computed only
 when asked for; nothing here runs at compile time.

@@ -399,10 +399,11 @@ def test_op_scopes_of_a_local_cg_session():
     module = re.match(r"HloModule ([^\s,]+)", text).group(1)
     assert {m for m, _ in scopes} == {module}
     by_op = {op: sc for (_, op), sc in scopes.items()}
-    # the 27-point stencil: one fusion of 26 shifted slices per apply
-    # (r0 = b - A x0 in init, q = A p in each step), placed by most of its
-    # fused instructions even where another scope's op is its root
-    stencils = [f for f, ops in _fusions(text).items() if ops["slice"] >= 26]
+    # the 27-point stencil: one fusion per apply (r0 = b - A x0 in init,
+    # q = A p in each step) of the box sum's three 3-point slice sums,
+    # placed by most of its fused instructions even where another scope's
+    # op is its root (init's subtract)
+    stencils = [f for f, ops in _fusions(text).items() if ops["slice"] >= 9]
     assert len(stencils) == 2
     assert all(by_op[f] == "repro.matvec" for f in stencils)
     # the dot products (a multiply and a sum): each a fusion of its own

@@ -8,6 +8,7 @@ import pytest
 from repro.core.operators import (
     STENCIL_7PT,
     STENCIL_27PT,
+    Stencil,
     build_dense_from_stencil,
     build_ell_from_stencil,
     touched_elements_per_iter,
@@ -107,3 +108,44 @@ def test_interior_shell_split_matches_monolithic(stencil, split_dims):
     assert y.shape == y_ref.shape
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=1e-6, atol=1e-6)
+
+
+def _slice_add(stencil, xp):
+    """The apply as one shifted add per neighbour, in the stencil's order."""
+    nx, ny, nz = (s - 2 for s in xp.shape)
+    acc = stencil.diag * xp[1:-1, 1:-1, 1:-1]
+    for dx, dy, dz in stencil.offsets:
+        acc = acc + stencil.off_coeff * xp[1 + dx:1 + dx + nx,
+                                           1 + dy:1 + dy + ny,
+                                           1 + dz:1 + dz + nz]
+    return acc
+
+
+@pytest.mark.parametrize("padded", [(10, 10, 10), (7, 9, 12), (5, 7, 3)],
+                         ids=["cubic", "non-cubic", "shell-slab"])
+@pytest.mark.parametrize("dtype", ["float32", "float64"])
+@pytest.mark.parametrize("stencil", [STENCIL_7PT, STENCIL_27PT],
+                         ids=lambda s: s.name)
+def test_apply_matches_slice_add(stencil, dtype, padded, f64):
+    """The 27-point apply (the separable box sum) equals the 26-slice sum
+    to rounding; the 7-point apply is the slice-add formula bit for bit."""
+    xp = jax.random.normal(jax.random.PRNGKey(4), padded, jnp.dtype(dtype))
+    q = np.asarray(stencil.matvec_padded(xp))
+    ref = np.asarray(_slice_add(stencil, xp))
+    assert q.dtype == ref.dtype == np.dtype(dtype)
+    assert q.shape == tuple(s - 2 for s in padded)
+    if stencil.is_box:
+        rtol = {"float32": 1e-6, "float64": 1e-14}[dtype]
+        assert np.max(np.abs(q - ref)) <= rtol * np.max(np.abs(ref))
+    else:
+        np.testing.assert_array_equal(q, ref)
+
+
+@pytest.mark.parametrize("offsets,box", [
+    (STENCIL_27PT.offsets, True),
+    (tuple(reversed(STENCIL_27PT.offsets)), True),
+    (STENCIL_7PT.offsets, False),
+    (STENCIL_27PT.offsets[1:], False),
+], ids=["27pt", "27pt-permuted", "7pt", "27pt-less-one"])
+def test_is_box(offsets, box):
+    assert Stencil(name="s", offsets=offsets, diag=27.0).is_box is box

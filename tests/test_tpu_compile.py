@@ -106,6 +106,25 @@ def test_xla_cg_loop_compiles_in_f64_for_v5e(one_chip):
     assert mem.temp_size_in_bytes < 100e6
 
 
+def test_27pt_apply_with_dot_at_512_compiles_for_v5e(one_chip):
+    """The one-chip cell's apply, q = A p with p·q, at 512³ float32.  The
+    separable box sum keeps the padded operand and two partial sums, each
+    about half a gigabyte; a formulation that stages much more (a 3-D
+    convolution staged 68.7 GB) fails here."""
+    from repro.core.methods import local_dot
+
+    op = LocalOp(STENCIL_27PT)
+
+    def apply(p):
+        q = op.matvec(p)
+        return q, local_dot(p, q)
+
+    p = _arg((512,) * 3, jnp.float32, one_chip)
+    compiled = jax.jit(apply).lower(p).compile()
+    assert "convolution" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.2e9
+
+
 def test_f64_pallas_request_raises_before_compiling(one_chip, monkeypatch):
     from repro.api import SolverOptions, SolverSession
     # steer the session as it is steered on a TPU backend
