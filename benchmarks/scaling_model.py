@@ -68,12 +68,13 @@ class MethodModel:
 
 
 #: derived from the solver registry — the per-iteration communication
-#: structure is method metadata, declared once in repro.api.registry.
+#: structure is method metadata, declared once in each MethodDef
+#: (repro.core.methods).
 METHODS = {
     name: MethodModel(name, spec.spmvs_per_iter,
                       tuple((h,) for h in spec.reduction_hides),
                       spec.halo_hides, spec.precond_applies_per_iter,
-                      getattr(spec.method_def, "refresh_spmvs", 0))
+                      spec.refresh_spmvs)
     for name, spec in REGISTRY.items()
 }
 

@@ -6,9 +6,9 @@ Modes::
     python -m repro.analysis --write AUDIT.json   # measure, (re)write baseline
     python -m repro.analysis --check AUDIT.json   # full audit, fail on drift
 
-``--check`` is the CI gate: all three passes plus the registry-vs-MethodDef
-field sweep, comparing the measured HLO against both the registry metadata
-and the committed byte-level baseline.  Exit status 1 on any violation.
+``--check`` is the CI gate: all three passes, comparing the measured HLO
+against both the MethodDef communication metadata and the committed
+byte-level baseline.  Exit status 1 on any violation.
 """
 
 from __future__ import annotations
@@ -16,37 +16,6 @@ from __future__ import annotations
 import argparse
 import json
 import sys
-
-
-def _registry_violations():
-    """Re-assert registry ↔ MethodDef consistency as auditable findings.
-
-    Registration already hard-fails on drift (``RegistryConsistencyError``),
-    so this sweep is clean by construction — it exists so the audit report
-    states the invariant was checked, and so a spec constructed outside
-    ``register_solver`` (tests, tools) is still caught.
-    """
-    from repro.analysis.violation import Violation
-    from repro.api.registry import REGISTRY, method_field_diff
-    from repro.core.methods import METHODS
-
-    out = []
-    for name in sorted(REGISTRY):
-        if name not in METHODS:
-            out.append(Violation("registry", name, "method_def",
-                                 expected="a registered MethodDef",
-                                 actual="missing"))
-            continue
-        for d in method_field_diff(REGISTRY[name], METHODS[name]):
-            out.append(Violation("registry", name, d.field,
-                                 expected=d.derived_value,
-                                 actual=d.registry_value,
-                                 detail="SolverSpec drifted from MethodDef"))
-    for name in sorted(set(METHODS) - set(REGISTRY)):
-        out.append(Violation("registry", name, "solver_spec",
-                             expected="a registry entry per MethodDef",
-                             actual="missing"))
-    return out
 
 
 def main(argv: list[str] | None = None) -> int:
@@ -72,19 +41,17 @@ def main(argv: list[str] | None = None) -> int:
 
     violations = []
 
-    print("[1/4] registry <-> MethodDef field sweep", flush=True)
-    violations += _registry_violations()
-    print("[2/4] MethodDef AST + state-layout lint", flush=True)
+    print("[1/3] MethodDef AST + state-layout lint", flush=True)
     violations += check_methods()
-    print("[3/4] Pallas kernel static checks", flush=True)
+    print("[2/3] Pallas kernel static checks", flush=True)
     violations += check_kernels()
 
     if args.lint_only:
-        print("[4/4] HLO comms/donation audit: skipped (--lint-only)")
+        print("[3/3] HLO comms/donation audit: skipped (--lint-only)")
     else:
         from repro.analysis.audit import compare, run_measurements
         methods = args.methods.split(",") if args.methods else None
-        print("[4/4] HLO comms/donation audit "
+        print("[3/3] HLO comms/donation audit "
               "(compiling every method x mesh in a subprocess)", flush=True)
         measured = run_measurements(methods)
         n_cfg = sum(len(measured.get(k, {})) for k in

@@ -7,7 +7,7 @@ devices), both blocking and overlapped halo modes, with and without the
 Pallas fused body where the method declares one, and with a bound Jacobi
 preconditioner where it accepts one — then asserts on the compiled HLO:
 
-* ``all-reduce`` count == ``SolverSpec.allreduces_per_iter`` (+ the
+* ``all-reduce`` count == ``MethodDef.allreduces_per_iter`` (+ the
   preconditioner's ``extra_reductions_per_apply`` × applies);
 * ``collective-permute`` count == halo exchanges × 2 × split dims, where
   halo exchanges = ``halo_exchanges_per_iter`` (+ the preconditioner's
@@ -41,7 +41,8 @@ import subprocess
 import sys
 
 from repro.analysis.violation import Violation
-from repro.api.registry import REGISTRY, SolverSpec
+from repro.api.registry import REGISTRY
+from repro.core.methods import MethodDef
 
 #: audit meshes: name -> (device grid, axis names, # grid dims actually split)
 MESHES: dict[str, tuple[tuple[int, ...], tuple[str, ...], int]] = {
@@ -74,7 +75,7 @@ def _precond_meta() -> dict[str, int]:
     }
 
 
-def comms_jobs(registry: dict[str, SolverSpec] | None = None) -> list[dict]:
+def comms_jobs(registry: dict[str, MethodDef] | None = None) -> list[dict]:
     """The comms audit matrix.  Key: ``method|mesh|halo|kernel|precond``."""
     registry = REGISTRY if registry is None else registry
     jobs = []
@@ -99,7 +100,7 @@ def comms_jobs(registry: dict[str, SolverSpec] | None = None) -> list[dict]:
     return jobs
 
 
-def expected_comms(spec: SolverSpec, mesh: str, *,
+def expected_comms(spec: MethodDef, mesh: str, *,
                    precond: str = "none",
                    precond_meta: dict[str, int] | None = None) -> dict[str, int]:
     """Collective counts the registry metadata predicts for one config."""
@@ -302,7 +303,7 @@ def run_measurements(methods: list[str] | None = None, *,
 # =============================================================================
 
 def compare(measured: dict,
-            registry: dict[str, SolverSpec] | None = None,
+            registry: dict[str, MethodDef] | None = None,
             baseline: dict | None = None) -> list[Violation]:
     """Every contract breach between measurement, registry and baseline."""
     registry = REGISTRY if registry is None else registry

@@ -13,7 +13,7 @@ import dataclasses
 
 @dataclasses.dataclass(frozen=True)
 class Violation:
-    pass_name: str      # "comms" | "donation" | "lint_methods" | "lint_kernels" | "registry" | "baseline"
+    pass_name: str      # "comms" | "donation" | "lint_methods" | "lint_kernels" | "baseline"
     subject: str        # e.g. "cg|1d|concat|xla|none", "method:cg", "kernel:spmv"
     field: str          # e.g. "all-reduce", "vmem_bytes", "traced_branch"
     expected: object

@@ -5,13 +5,9 @@ from repro.api.backend import (Backend, resolve_backend, resolve_halo_mode,
 from repro.api.options import HALO_MODES, LAYOUTS, SolverOptions
 from repro.api.registry import (
     REGISTRY,
-    RegistryConsistencyError,
-    SolverSpec,
-    check_consistent_with_core,
     fallback_chain,
     fused_solver_names,
     get_solver,
-    register_solver,
     solver_names,
     variant_pairs,
 )
@@ -26,17 +22,13 @@ __all__ = [
     "PRECONDITIONERS",
     "Preconditioner",
     "REGISTRY",
-    "RegistryConsistencyError",
     "SolverOptions",
     "SolverSession",
-    "SolverSpec",
-    "check_consistent_with_core",
     "fallback_chain",
     "fused_solver_names",
     "get_solver",
     "make_precond",
     "precond_names",
-    "register_solver",
     "resolve_backend",
     "resolve_halo_mode",
     "resolve_matvec",

@@ -1,259 +1,23 @@
-"""Solver registry: methods + the metadata the paper reasons about.
+"""Solver registry: a read-only view over ``repro.core.methods.METHODS``.
 
-Subsumes the bare ``SOLVERS`` / ``VARIANT_OF`` dicts in ``core.solvers``:
-each entry carries the per-iteration communication structure (reductions,
-how each one hides, SpMV count) that drives the scaling model and the
-barrier-structure reporting, plus solver-selection facts (SPD requirement,
-stationary vs Krylov, which classical method a variant descends from).
-
-New methods register once here and every driver — launch, benchmarks,
-examples, the dry-run — picks them up.
+Each method is declared once, as a ``MethodDef`` passed to
+``register_method``; that declaration carries the per-iteration
+communication structure (reductions, how each one hides, SpMV and halo
+counts) that drives the scaling model and the barrier-structure reporting,
+plus the solver-selection facts (SPD requirement, stationary vs Krylov,
+which classical method a variant descends from).  Every driver — launch,
+benchmarks, examples, the dry-run — reads it through these helpers.
 """
 
 from __future__ import annotations
 
-import dataclasses
-from typing import Callable
-
-from repro.core import solvers as _solvers
 from repro.core.methods import METHODS, MethodDef
 
-#: how a reduction's latency is hidden (the scaling model's terms):
-#: "none" = blocking barrier, "vec" = overlapped with one vector update,
-#: "spmv" = overlapped with the SpMV, "pipe" = a pipelined stacked
-#: reduction overlapped with the NEXT SpMV (+ preconditioner apply) —
-#: the Ghysels–Vanroose window, priced by scaling_model's t_reduce term.
-HideKind = str
-
-#: accepted ``SolverSpec.reduce_hide`` values — the variant's reduction
-#: *scheduling strategy* (orthogonal to the per-reduction hide kinds):
-#: "none"      = one psum per dot product (the classics + the paper's
-#:               nonblocking variants),
-#: "merged"    = every dot of the iteration stacked into ONE psum
-#:               (Chronopoulos–Gear CG, single-reduction BiCGStab),
-#: "pipelined" = the ONE stacked psum additionally overlapped with the
-#:               body's SpMV (Ghysels–Vanroose).
-REDUCE_HIDES = ("none", "merged", "pipelined")
-
-#: how a SpMV's halo exchange hides (one entry per SpMV per iteration):
-#: "interior" = the ppermutes ride behind the interior stencil apply
-#: (halo_mode="overlap"), "none" = the consumer needs the halos immediately
-#: (the Gauss-Seidel sweeps: the very first plane/colour reads them).
-HaloHideKind = str
+#: name -> MethodDef; the same dict object as ``core.methods.METHODS``
+REGISTRY: dict[str, MethodDef] = METHODS
 
 
-@dataclasses.dataclass(frozen=True)
-class SolverSpec:
-    """A solver plus the metadata the drivers and models need."""
-
-    name: str
-    fn: Callable                      # (A, b, x0, *, tol, maxiter, dot, norm_ref)
-    reduction_hides: tuple[HideKind, ...]
-    spmvs_per_iter: int
-    halo_hides: tuple[HaloHideKind, ...] = ()   # defaults to all-"interior"
-    variant_of: str | None = None     # classical baseline this method refines
-    spd_required: bool = False
-    stationary: bool = False          # Jacobi/GS family (vs Krylov)
-    accepts_precond: bool = False     # fn takes M= (repro.precond apply)
-    precond_applies_per_iter: int = 0  # M^{-1} applications per iteration
-    reduce_hide: str = "none"         # reduction scheduling (REDUCE_HIDES)
-    fused_kernels: tuple[str, ...] = ()  # Pallas fused-body capability
-    #: COMPILED all-reduces per iteration body — what `python -m
-    #: repro.analysis` asserts on the HLO of every mesh shape.  Defaults to
-    #: ``reductions_per_iter``; set explicitly where the implementation fuses
-    #: logical reductions into one collective (pcg rides r·z and r·r on a
-    #: single psum pair, so 3 logical reductions compile to 2 all-reduces).
-    allreduces_per_iter: int | None = None
-    #: halo exchanges (``pad_exchange`` calls) per iteration body; each one
-    #: compiles to ``2 × n_split_dims`` collective-permutes.  Defaults to
-    #: ``spmvs_per_iter``; the Gauss-Seidel sweeps exchange per plane/colour
-    #: half-sweep plus once for the residual matvec, so they set it higher.
-    halo_exchanges_per_iter: int | None = None
-    description: str = ""
-    #: the single-source algorithm definition (repro.core.methods); attached
-    #: and cross-validated by register_solver — every metadata field that IS
-    #: derivable from the definition must agree with it.
-    method_def: MethodDef | None = None
-
-    def __post_init__(self):
-        if not self.halo_hides:
-            object.__setattr__(
-                self, "halo_hides", ("interior",) * self.spmvs_per_iter)
-        if len(self.halo_hides) != self.spmvs_per_iter:
-            raise ValueError(
-                f"{self.name!r}: halo_hides needs one entry per SpMV "
-                f"({len(self.halo_hides)} != {self.spmvs_per_iter})")
-        if self.precond_applies_per_iter and not self.accepts_precond:
-            raise ValueError(
-                f"{self.name!r}: precond_applies_per_iter without "
-                f"accepts_precond")
-        if self.reduce_hide not in REDUCE_HIDES:
-            raise ValueError(
-                f"{self.name!r}: unknown reduce_hide {self.reduce_hide!r}; "
-                f"options: {REDUCE_HIDES}")
-        if self.reduce_hide != "none" and len(self.reduction_hides) != 1:
-            raise ValueError(
-                f"{self.name!r}: reduce_hide={self.reduce_hide!r} means ONE "
-                f"stacked reduction per iteration, but reduction_hides has "
-                f"{len(self.reduction_hides)} entries")
-        if self.reduce_hide == "pipelined" and self.reduction_hides != ("pipe",):
-            raise ValueError(
-                f"{self.name!r}: a pipelined variant's single reduction "
-                f"hides behind the next SpMV — reduction_hides must be "
-                f"('pipe',)")
-        if self.allreduces_per_iter is None:
-            object.__setattr__(
-                self, "allreduces_per_iter", self.reductions_per_iter)
-        if self.halo_exchanges_per_iter is None:
-            object.__setattr__(
-                self, "halo_exchanges_per_iter", self.spmvs_per_iter)
-        if self.reduce_hide != "none" and self.allreduces_per_iter != 1:
-            raise ValueError(
-                f"{self.name!r}: reduce_hide={self.reduce_hide!r} claims ONE "
-                f"stacked reduction but allreduces_per_iter="
-                f"{self.allreduces_per_iter}")
-        if self.allreduces_per_iter > self.reductions_per_iter:
-            raise ValueError(
-                f"{self.name!r}: allreduces_per_iter "
-                f"({self.allreduces_per_iter}) exceeds the declared logical "
-                f"reductions ({self.reductions_per_iter}) — fusing can only "
-                f"reduce the collective count")
-        if self.halo_exchanges_per_iter < self.spmvs_per_iter:
-            raise ValueError(
-                f"{self.name!r}: halo_exchanges_per_iter "
-                f"({self.halo_exchanges_per_iter}) below spmvs_per_iter "
-                f"({self.spmvs_per_iter}) — every SpMV needs its halos")
-
-    @property
-    def reductions_per_iter(self) -> int:
-        return len(self.reduction_hides)
-
-    @property
-    def blocking_reductions(self) -> int:
-        """Reductions with no overlap window (the paper's hard barriers)."""
-        return sum(1 for h in self.reduction_hides if h == "none")
-
-    @property
-    def hidden_halos(self) -> int:
-        """SpMVs whose halo exchange overlaps interior compute."""
-        return sum(1 for h in self.halo_hides if h == "interior")
-
-    @property
-    def has_fused_body(self) -> bool:
-        """Whether the method declares a fused Pallas iteration body — the
-        capability the facade's ``pallas=True`` routing queries."""
-        return bool(self.fused_kernels)
-
-
-REGISTRY: dict[str, SolverSpec] = {}
-
-
-class RegistryConsistencyError(RuntimeError):
-    """The registry drifted from what ``core.solvers``/``core.methods``
-    export.  The message renders every mismatched field as an
-    expected-vs-actual table (method, field, registry value, derived value)
-    so a drifted registration reads as a diff, not a bare assertion."""
-
-
-@dataclasses.dataclass(frozen=True)
-class FieldDiff:
-    """One registry-vs-derived mismatch (a row of the consistency report)."""
-
-    method: str
-    field: str
-    registry_value: object
-    derived_value: object
-
-    def __str__(self) -> str:
-        return (f"{self.method}.{self.field}: registry declares "
-                f"{self.registry_value!r}, derived says {self.derived_value!r}")
-
-
-def format_field_diffs(diffs: list[FieldDiff]) -> str:
-    """Render mismatches as an aligned expected-vs-actual table."""
-    rows = [("method", "field", "registry", "derived")]
-    rows += [(d.method, d.field, repr(d.registry_value), repr(d.derived_value))
-             for d in diffs]
-    widths = [max(len(r[c]) for r in rows) for c in range(4)]
-    lines = ["  ".join(cell.ljust(w) for cell, w in zip(row, widths)).rstrip()
-             for row in rows]
-    lines.insert(1, "  ".join("-" * w for w in widths))
-    return "\n".join(lines)
-
-
-def method_field_diff(spec: SolverSpec, mdef: MethodDef) -> list[FieldDiff]:
-    """Registry fields that are derivable from the MethodDef but disagree
-    with it — the definition is the single source of truth.  Empty list ==
-    consistent.  Exported for ``repro.analysis`` (the audit re-runs this
-    diff across the whole registry instead of trusting the import-time
-    check ran under the same code)."""
-    derived = {
-        "stationary": mdef.stationary,
-        "accepts_precond": mdef.accepts_precond,
-        "reduce_hide": mdef.reduce_hide,
-        "variant_of": mdef.variant_of,
-        "fused_kernels": mdef.fused_kernels,
-    }
-    return [
-        FieldDiff(spec.name, field, getattr(spec, field), want)
-        for field, want in derived.items()
-        if getattr(spec, field) != want
-    ]
-
-
-def fused_capability_diff(spec: SolverSpec, mdef: MethodDef) -> list[FieldDiff]:
-    """The ``fused_kernels`` capability must be *executable*, not just
-    declared: a non-empty tuple requires the MethodDef to carry a fused
-    body (``fused_step``/``fused_init``), and every named kernel must be a
-    real ``PallasOp`` hook.  Before this check a capability typo silently
-    routed ``pallas=True`` to the unfused path (``has_fused_body`` was
-    true, the hook lookup failed only at trace time — or never, if the
-    name drifted from the hook it meant)."""
-    if not spec.fused_kernels:
-        return []
-    from repro.kernels.pallas_op import PallasOp
-
-    diffs = []
-    if mdef.fused_step is None or mdef.fused_init is None:
-        diffs.append(FieldDiff(
-            spec.name, "fused_kernels", spec.fused_kernels,
-            "() — MethodDef has no fused body (fused_step/fused_init)"))
-    missing = tuple(k for k in spec.fused_kernels
-                    if not callable(getattr(PallasOp, k, None)))
-    if missing:
-        diffs.append(FieldDiff(
-            spec.name, "fused_kernels", spec.fused_kernels,
-            f"PallasOp hooks — {missing} not found on PallasOp"))
-    return diffs
-
-
-def _validate_against_method(spec: SolverSpec, mdef: MethodDef) -> None:
-    diffs = method_field_diff(spec, mdef) + fused_capability_diff(spec, mdef)
-    if diffs:
-        raise RegistryConsistencyError(
-            f"{spec.name!r} drifted from its MethodDef:\n"
-            + format_field_diffs(diffs))
-
-
-def register_solver(spec: SolverSpec) -> SolverSpec:
-    if spec.name in REGISTRY:
-        raise ValueError(f"solver {spec.name!r} already registered")
-    if spec.variant_of is not None and spec.variant_of not in REGISTRY:
-        raise ValueError(
-            f"{spec.name!r}: unknown baseline {spec.variant_of!r} "
-            f"(register the classical method first)")
-    if spec.name not in METHODS:
-        raise RegistryConsistencyError(
-            f"{spec.name!r}: no MethodDef in repro.core.methods — define the "
-            f"algorithm first (docs/API.md §'Authoring a new method')")
-    mdef = METHODS[spec.name]
-    _validate_against_method(spec, mdef)
-    object.__setattr__(spec, "method_def", mdef)
-    REGISTRY[spec.name] = spec
-    return spec
-
-
-def get_solver(name: str) -> SolverSpec:
+def get_solver(name: str) -> MethodDef:
     try:
         return REGISTRY[name]
     except KeyError:
@@ -286,166 +50,7 @@ def fallback_chain(name: str) -> list[str]:
     return chain
 
 
-# --- the seven methods of the paper ------------------------------------------
-# Reduction structure per §3.1/Fig. 1; SpMV counts per the touched-elements
-# model.  Stationary methods report one residual-norm reduction per sweep.
-# halo_hides: Krylov/Jacobi SpMVs split interior/shell (halo_mode="overlap"),
-# the GS sweeps consume their halos at the first plane/colour -> "none".
-
-register_solver(SolverSpec(
-    name="jacobi", fn=_solvers.jacobi,
-    reduction_hides=("none",), spmvs_per_iter=1, stationary=True,
-    description="x += D^-1 r; 1 SpMV + 1 blocking residual reduction"))
-
-register_solver(SolverSpec(
-    name="gauss_seidel_rb", fn=_solvers.sym_gauss_seidel_rb,
-    reduction_hides=("none",), spmvs_per_iter=2, stationary=True,
-    halo_hides=("none", "none"),
-    halo_exchanges_per_iter=5,   # 4 colour half-sweeps + the residual matvec
-    description="red-black coloured symmetric Gauss-Seidel (§3.4)"))
-
-register_solver(SolverSpec(
-    name="gauss_seidel", fn=_solvers.sym_gauss_seidel_relaxed,
-    reduction_hides=("none",), spmvs_per_iter=2, stationary=True,
-    halo_hides=("none", "none"),
-    halo_exchanges_per_iter=3,   # fwd + bwd plane sweeps + residual matvec
-    variant_of="gauss_seidel_rb",
-    description="relaxed tasked symmetric GS (§3.4 Code 4, TPU adaptation)"))
-
-register_solver(SolverSpec(
-    name="cg", fn=_solvers.cg,
-    reduction_hides=("none", "vec"), spmvs_per_iter=1, spd_required=True,
-    description="classical conjugate gradient (2 blocking reductions)"))
-
-register_solver(SolverSpec(
-    name="cg_nb", fn=_solvers.cg_nb,
-    reduction_hides=("spmv", "vec"), spmvs_per_iter=1, spd_required=True,
-    variant_of="cg",
-    description="nonblocking CG (Alg. 1): both reductions off the critical path"))
-
-register_solver(SolverSpec(
-    name="pcg", fn=_solvers.pcg,
-    reduction_hides=("none", "none", "vec"), spmvs_per_iter=1,
-    spd_required=True, variant_of="cg",
-    allreduces_per_iter=2,       # the (r·z, r·r) pair rides ONE psum (dot2)
-    accepts_precond=True, precond_applies_per_iter=1,
-    description="preconditioned CG (repro.precond): p·Ap and r·z block, "
-                "r·r feeds only the check; +0 reductions from the "
-                "built-in preconditioners"))
-
-register_solver(SolverSpec(
-    name="bicgstab", fn=_solvers.bicgstab,
-    reduction_hides=("none", "none", "vec"), spmvs_per_iter=2,
-    description="classical BiCGStab (3 blocking reductions)"))
-
-register_solver(SolverSpec(
-    name="bicgstab_b1", fn=_solvers.bicgstab_b1,
-    reduction_hides=("none", "vec", "vec"), spmvs_per_iter=2,
-    variant_of="bicgstab",
-    description="BiCGStab one-blocking (Alg. 2) with restart"))
-
-
-register_solver(SolverSpec(
-    name="pbicgstab", fn=_solvers.pbicgstab,
-    reduction_hides=("none", "none", "vec"), spmvs_per_iter=2,
-    variant_of="bicgstab",
-    accepts_precond=True, precond_applies_per_iter=2,
-    description="right-preconditioned BiCGStab (true-residual stopping)"))
-
-
-# --- PR 4: reduction-hiding variants (merged + pipelined) --------------------
-# One stacked psum per iteration; "merged" pays it as a single blocking
-# barrier, "pipelined" hides it behind the body's SpMV ("pipe" hide kind).
-# tests/test_hlo_analysis.py asserts the one-all-reduce claim on compiled
-# shard_map iteration bodies.
-
-register_solver(SolverSpec(
-    name="cg_merged", fn=_solvers.cg_merged,
-    reduction_hides=("none",), spmvs_per_iter=1, spd_required=True,
-    variant_of="cg", reduce_hide="merged",
-    fused_kernels=("cg_body", "spmv_dots"),
-    description="Chronopoulos–Gear CG: all dots in ONE stacked psum "
-                "(Saad recurrence for p·Ap)"))
-
-register_solver(SolverSpec(
-    name="cg_pipe", fn=_solvers.cg_pipe,
-    reduction_hides=("pipe",), spmvs_per_iter=1, spd_required=True,
-    variant_of="cg", reduce_hide="pipelined",
-    fused_kernels=("spmv_dots3", "pipe_body"),
-    description="Ghysels–Vanroose pipelined CG: the ONE stacked psum "
-                "overlaps the SpMV"))
-
-register_solver(SolverSpec(
-    name="pcg_merged", fn=_solvers.pcg_merged,
-    reduction_hides=("none",), spmvs_per_iter=1, spd_required=True,
-    variant_of="pcg", reduce_hide="merged",
-    accepts_precond=True, precond_applies_per_iter=1,
-    fused_kernels=("pcg_body", "spmv_dots3"),
-    description="merged-reduction PCG (Chronopoulos–Gear with M)"))
-
-register_solver(SolverSpec(
-    name="pcg_pipe", fn=_solvers.pcg_pipe,
-    reduction_hides=("pipe",), spmvs_per_iter=1, spd_required=True,
-    variant_of="pcg", reduce_hide="pipelined",
-    accepts_precond=True, precond_applies_per_iter=1,
-    fused_kernels=("fused_dots", "ppipe_body"),
-    description="pipelined PCG: the stacked psum overlaps M-apply + SpMV"))
-
-register_solver(SolverSpec(
-    name="bicgstab_merged", fn=_solvers.bicgstab_merged,
-    reduction_hides=("none",), spmvs_per_iter=2,
-    variant_of="bicgstab", reduce_hide="merged",
-    fused_kernels=("bicgstab_spmv_dots", "bicgstab_update1",
-                   "bicgstab_spmv_update"),
-    description="single-reduction BiCGStab: nine dots, ONE stacked psum "
-                "(Cools–Vanroose recurrences)"))
-
-register_solver(SolverSpec(
-    name="pbicgstab_merged", fn=_solvers.pbicgstab_merged,
-    reduction_hides=("none",), spmvs_per_iter=2,
-    variant_of="pbicgstab", reduce_hide="merged",
-    accepts_precond=True, precond_applies_per_iter=2,
-    fused_kernels=("bicgstab_spmv_dots", "bicgstab_update1",
-                   "bicgstab_spmv_update"),
-    description="right-preconditioned single-reduction BiCGStab "
-                "(merged core on A∘M⁻¹, true-residual stopping)"))
-
-
 def fused_solver_names() -> list[str]:
     """Methods whose MethodDef declares a fused Pallas iteration body — the
     capability query behind the facade's ``pallas=True`` routing."""
     return sorted(n for n, s in REGISTRY.items() if s.has_fused_body)
-
-
-def check_consistent_with_core(registry=None, solvers=None,
-                               variant_of=None) -> None:
-    """The registry must cover exactly what core.solvers exports.
-
-    Raises :class:`RegistryConsistencyError` — deliberately NOT ``assert``:
-    this guard runs at import time and must survive ``python -O`` / ``-OO``,
-    where asserts are compiled away (the bug this replaces: a drifted
-    registry imported cleanly under optimised bytecode).  The keyword
-    arguments exist so tests can feed deliberately inconsistent tables;
-    production callers use the defaults.
-    """
-    registry = REGISTRY if registry is None else registry
-    solvers = _solvers.SOLVERS if solvers is None else solvers
-    variant_of = _solvers.VARIANT_OF if variant_of is None else variant_of
-    if set(registry) != set(solvers):
-        raise RegistryConsistencyError(
-            f"method sets differ: registry-only="
-            f"{sorted(set(registry) - set(solvers))}, "
-            f"core-only={sorted(set(solvers) - set(registry))}")
-    for name, spec in registry.items():
-        if spec.fn is not solvers[name]:
-            raise RegistryConsistencyError(
-                f"{name!r}: registered fn is not core.solvers.SOLVERS[{name!r}]")
-    for variant, base in variant_of.items():
-        if variant not in registry or registry[variant].variant_of != base:
-            raise RegistryConsistencyError(
-                f"{variant!r}: registry variant_of="
-                f"{registry[variant].variant_of if variant in registry else '<missing>'!r}"
-                f" but core says {base!r}")
-
-
-check_consistent_with_core()

@@ -33,13 +33,14 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.api.backend import (Backend, resolve_backend, resolve_halo_mode,
                                resolve_matvec, resolve_precond)
 from repro.api.options import SolverOptions
-from repro.api.registry import SolverSpec, fallback_chain, get_solver
+from repro.api.registry import REGISTRY, fallback_chain, get_solver
 from repro.api.timing import timed_result
 from repro.core.distributed import DistributedOp, solve_shardmap, solve_step_shardmap
 from repro.core.methods import (STATUS_BREAKDOWN, STATUS_DIVERGED,
-                                STATUS_STAGNATED, SolveBreakdown, status_name)
+                                STATUS_STAGNATED, MethodDef, SolveBreakdown,
+                                status_name)
 from repro.core.problems import HPCGProblem, make_problem
-from repro.core.solvers import LocalOp, SolveResult
+from repro.core.solvers import SOLVERS, LocalOp, SolveResult
 from repro.obs import trace as obs
 from repro.obs.scopes import op_scopes
 
@@ -102,31 +103,28 @@ class SolverSession:
         # solve (inputs, with compile in _executable on a miss; execute)
         with obs.span("resolve", method=method, layout=self.options.layout,
                       grid=list(problem.shape)):
-            self.spec: SolverSpec = get_solver(method)
+            self.mdef: MethodDef = get_solver(method)
             self.backend: Backend = backend or resolve_backend(self.options,
                                                                mesh=mesh)
             self._matvec = resolve_matvec(problem.stencil, self.options)
             self.halo_mode = resolve_halo_mode(self.options)
         with obs.span("precond.setup", precond=self.options.precond):
             self.precond = resolve_precond(self.options)
-        if self.precond is not None and not self.spec.accepts_precond:
-            from repro.api.registry import REGISTRY
+        if self.precond is not None and not self.mdef.accepts_precond:
             takers = sorted(n for n, s in REGISTRY.items()
                             if s.accepts_precond)
             raise ValueError(
                 f"method {self.method!r} takes no preconditioner; use one "
                 f"of {takers} with precond={self.options.precond!r}, or "
                 f"precond='none'")
-        if (self.precond is not None and self.spec.spd_required
+        if (self.precond is not None and self.mdef.spd_required
                 and not self.precond.spd_preserving):
             raise ValueError(
                 f"method {self.method!r} requires an SPD-preserving "
                 f"preconditioner, but {self.precond.describe()} declares "
                 f"spd_preserving=False; use pbicgstab or an SPD-preserving "
                 f"M (CG's short recurrence silently breaks down otherwise)")
-        mdef = getattr(self.spec, "method_def", None)
-        if (self.options.residual_replacement
-                and not (mdef is not None and mdef.has_refresh)):
+        if self.options.residual_replacement and not self.mdef.has_refresh:
             raise ValueError(
                 f"residual_replacement={self.options.residual_replacement} "
                 f"but method {self.method!r} declares no refresh hook; "
@@ -152,7 +150,7 @@ class SolverSession:
     # -- introspection --------------------------------------------------------
     @property
     def method(self) -> str:
-        return self.spec.name
+        return self.mdef.name
 
     @property
     def layout(self):
@@ -168,10 +166,9 @@ class SolverSession:
     def _solver_kwargs(self, A) -> dict:
         """tol/maxiter/norm_ref plus the bound preconditioner apply (and
         the telemetry row bound when convergence telemetry is on — only
-        passed when enabled, so a custom registry ``fn`` that predates the
-        keyword keeps working)."""
+        passed when enabled)."""
         kw = self.options.solver_kwargs()
-        if self.spec.accepts_precond:
+        if self.mdef.accepts_precond:
             kw["M"] = None if self.precond is None else self.precond.bind(A)
         rows = self.options.telemetry_rows()
         if rows:
@@ -198,8 +195,8 @@ class SolverSession:
         inside the fused body (its own Pallas kernels when
         ``use_pallas``), so ``pcg_merged + chebyshev`` runs end-to-end on
         the 2-HBM-pass path."""
-        return (self.options.pallas and self.spec.has_fused_body
-                and (self.precond is None or self.spec.accepts_precond)
+        return (self.options.pallas and self.mdef.has_fused_body
+                and (self.precond is None or self.mdef.accepts_precond)
                 and self.options.matvec_padded is None
                 and self.options.dot is None)
 
@@ -217,7 +214,7 @@ class SolverSession:
                 from repro.core.methods import Ops, run_method
                 from repro.kernels.pallas_op import PallasOp
                 A = PallasOp(LocalOp(self.problem.stencil))
-                mdef = self.spec.method_def
+                mdef = self.mdef
                 M = (None if self.precond is None
                      else self.precond.bind(A))
 
@@ -245,8 +242,8 @@ class SolverSession:
             A = LocalOp(self.problem.stencil, matvec_padded=self._matvec)
 
             def run(b, x0):
-                return self.spec.fn(A, b, x0, dot=opts.dot,
-                                    **self._solver_kwargs(A))
+                return SOLVERS[self.method](A, b, x0, dot=opts.dot,
+                                            **self._solver_kwargs(A))
 
             return jax.jit(run, **jit_kw)
         fn, _ = solve_shardmap(
@@ -428,13 +425,11 @@ class SolverSession:
                 or opts.dot is not None):
             plan.append((self.method, base))
         for name in fallback_chain(self.method)[1:]:
-            spec = get_solver(name)
+            mdef = get_solver(name)
             o = base
-            if not spec.accepts_precond and o.precond != "none":
+            if not mdef.accepts_precond and o.precond != "none":
                 o = o.replace(precond="none", precond_params=None)
-            mdef = getattr(spec, "method_def", None)
-            if o.residual_replacement and not (mdef is not None
-                                               and mdef.has_refresh):
+            if o.residual_replacement and not mdef.has_refresh:
                 o = o.replace(residual_replacement=0)
             plan.append((name, o))
         self._fallbacks = [
@@ -479,8 +474,8 @@ class SolverSession:
             A = LocalOp(self.problem.stencil, matvec_padded=self._matvec)
 
             def run(b, x0):
-                return self.spec.fn(A, b, x0, dot=opts.dot,
-                                    **self._solver_kwargs(A))
+                return SOLVERS[self.method](A, b, x0, dot=opts.dot,
+                                            **self._solver_kwargs(A))
 
             return jax.jit(jax.vmap(run), **jit_kw)
 
@@ -490,8 +485,8 @@ class SolverSession:
         def local_solve(b_loc, x0_loc):
             op = DistributedOp(stencil, layout, matvec_padded=self._matvec,
                                halo_mode=self.halo_mode)
-            return self.spec.fn(op, b_loc, x0_loc, dot=op.dot,
-                                **self._solver_kwargs(op))
+            return SOLVERS[self.method](op, b_loc, x0_loc, dot=op.dot,
+                                        **self._solver_kwargs(op))
 
         bspec = P(None, *layout.dim_axes)
         fn = jax.shard_map(

@@ -23,7 +23,6 @@ from repro.core.methods import (
 from repro.core.problems import HPCGProblem, default_dtype, enable_f64, make_problem
 from repro.core.solvers import (
     SOLVERS,
-    VARIANT_OF,
     LocalOp,
     SolveResult,
     bicgstab,

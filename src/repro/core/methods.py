@@ -297,6 +297,30 @@ class Ops:
         return self.A.diag
 
 
+#: how a reduction's latency is hidden (the scaling model's terms):
+#: "none" = blocking barrier, "vec" = overlapped with one vector update,
+#: "spmv" = overlapped with the SpMV, "pipe" = a pipelined stacked
+#: reduction overlapped with the NEXT SpMV (+ preconditioner apply) —
+#: the Ghysels–Vanroose window, priced by scaling_model's t_reduce term.
+HideKind = str
+
+#: accepted ``MethodDef.reduce_hide`` values — the variant's reduction
+#: *scheduling strategy* (orthogonal to the per-reduction hide kinds):
+#: "none"      = one psum per dot product (the classics + the paper's
+#:               nonblocking variants),
+#: "merged"    = every dot of the iteration stacked into ONE psum
+#:               (Chronopoulos–Gear CG, single-reduction BiCGStab),
+#: "pipelined" = the ONE stacked psum additionally overlapped with the
+#:               body's SpMV (Ghysels–Vanroose).
+REDUCE_HIDES = ("none", "merged", "pipelined")
+
+#: how a SpMV's halo exchange hides (one entry per SpMV per iteration):
+#: "interior" = the ppermutes ride behind the interior stencil apply
+#: (halo_mode="overlap"), "none" = the consumer needs the halos immediately
+#: (the Gauss-Seidel sweeps: the very first plane/colour reads them).
+HaloHideKind = str
+
+
 @dataclasses.dataclass(frozen=True)
 class MethodDef:
     """One iterative method, defined once, executed by pluggable runtimes.
@@ -307,8 +331,10 @@ class MethodDef:
     driver's convergence check and history read.  ``fused_init``/
     ``fused_step`` (present iff ``fused_kernels`` is non-empty) are the
     same iteration expressed against the fused-kernel hooks of
-    ``kernels.pallas_op.PallasOp`` — the capability the registry and the
-    facade's Pallas routing query.
+    ``kernels.pallas_op.PallasOp`` — the capability the facade's Pallas
+    routing queries.  The per-iteration communication structure
+    (``reduction_hides`` … ``halo_exchanges_per_iter``) is what the scaling
+    model, the barrier-structure reporting and the HLO audit read.
     """
 
     name: str
@@ -317,11 +343,13 @@ class MethodDef:
     res_scalar: str                   # scalar slot holding ||r||^2 (estimate)
     init: Callable                    # (ops, x0) -> state
     step: Callable                    # (ops, state) -> state
+    reduction_hides: tuple[HideKind, ...]   # per logical reduction
+    spmvs_per_iter: int
     finalize: Callable | None = None  # (ops, x0, state) -> x
     variant_of: str | None = None     # classical baseline this method refines
     accepts_precond: bool = False     # init/step consult ops.M
     stationary: bool = False          # Jacobi/GS family (vs Krylov)
-    reduce_hide: str = "none"         # "none" | "merged" | "pipelined"
+    reduce_hide: str = "none"         # reduction scheduling (REDUCE_HIDES)
     params: tuple[str, ...] = ()      # tuning knobs read from ops.params
     default_maxiter: int = 500
     fused_kernels: tuple[str, ...] = ()   # PallasOp hooks the fused body uses
@@ -342,6 +370,21 @@ class MethodDef:
     #: SpMV-equivalents one refresh costs (scaling-model price of the
     #: residual-replacement cadence); required iff ``refresh`` is set.
     refresh_spmvs: int = 0
+    halo_hides: tuple[HaloHideKind, ...] = ()   # defaults to all-"interior"
+    spd_required: bool = False
+    precond_applies_per_iter: int = 0  # M^{-1} applications per iteration
+    #: COMPILED all-reduces per iteration body — what `python -m
+    #: repro.analysis` asserts on the HLO of every mesh shape.  Defaults to
+    #: ``reductions_per_iter``; set explicitly where the implementation fuses
+    #: logical reductions into one collective (pcg rides r·z and r·r on a
+    #: single psum pair, so 3 logical reductions compile to 2 all-reduces).
+    allreduces_per_iter: int | None = None
+    #: halo exchanges (``pad_exchange`` calls) per iteration body; each one
+    #: compiles to ``2 × n_split_dims`` collective-permutes.  Defaults to
+    #: ``spmvs_per_iter``; the Gauss-Seidel sweeps exchange per plane/colour
+    #: half-sweep plus once for the residual matvec, so they set it higher.
+    halo_exchanges_per_iter: int | None = None
+    description: str = ""
 
     def __post_init__(self):
         if self.res_scalar not in self.scalars:
@@ -358,11 +401,72 @@ class MethodDef:
             raise ValueError(
                 f"{self.name!r}: refresh and refresh_spmvs must be declared "
                 f"together (the scaling model prices every refresh hook)")
+        if not self.halo_hides:
+            object.__setattr__(
+                self, "halo_hides", ("interior",) * self.spmvs_per_iter)
+        if len(self.halo_hides) != self.spmvs_per_iter:
+            raise ValueError(
+                f"{self.name!r}: halo_hides needs one entry per SpMV "
+                f"({len(self.halo_hides)} != {self.spmvs_per_iter})")
+        if self.precond_applies_per_iter and not self.accepts_precond:
+            raise ValueError(
+                f"{self.name!r}: precond_applies_per_iter without "
+                f"accepts_precond")
+        if self.reduce_hide not in REDUCE_HIDES:
+            raise ValueError(
+                f"{self.name!r}: unknown reduce_hide {self.reduce_hide!r}; "
+                f"options: {REDUCE_HIDES}")
+        if self.reduce_hide != "none" and len(self.reduction_hides) != 1:
+            raise ValueError(
+                f"{self.name!r}: reduce_hide={self.reduce_hide!r} means ONE "
+                f"stacked reduction per iteration, but reduction_hides has "
+                f"{len(self.reduction_hides)} entries")
+        if self.reduce_hide == "pipelined" and self.reduction_hides != ("pipe",):
+            raise ValueError(
+                f"{self.name!r}: a pipelined variant's single reduction "
+                f"hides behind the next SpMV — reduction_hides must be "
+                f"('pipe',)")
+        if self.allreduces_per_iter is None:
+            object.__setattr__(
+                self, "allreduces_per_iter", self.reductions_per_iter)
+        if self.halo_exchanges_per_iter is None:
+            object.__setattr__(
+                self, "halo_exchanges_per_iter", self.spmvs_per_iter)
+        if self.reduce_hide != "none" and self.allreduces_per_iter != 1:
+            raise ValueError(
+                f"{self.name!r}: reduce_hide={self.reduce_hide!r} claims ONE "
+                f"stacked reduction but allreduces_per_iter="
+                f"{self.allreduces_per_iter}")
+        if self.allreduces_per_iter > self.reductions_per_iter:
+            raise ValueError(
+                f"{self.name!r}: allreduces_per_iter "
+                f"({self.allreduces_per_iter}) exceeds the declared logical "
+                f"reductions ({self.reductions_per_iter}) — fusing can only "
+                f"reduce the collective count")
+        if self.halo_exchanges_per_iter < self.spmvs_per_iter:
+            raise ValueError(
+                f"{self.name!r}: halo_exchanges_per_iter "
+                f"({self.halo_exchanges_per_iter}) below spmvs_per_iter "
+                f"({self.spmvs_per_iter}) — every SpMV needs its halos")
 
     @property
     def res_index(self) -> int:
         """Flat state index of the ``res_scalar`` slot."""
         return len(self.vectors) + self.scalars.index(self.res_scalar)
+
+    @property
+    def reductions_per_iter(self) -> int:
+        return len(self.reduction_hides)
+
+    @property
+    def blocking_reductions(self) -> int:
+        """Reductions with no overlap window (the paper's hard barriers)."""
+        return sum(1 for h in self.reduction_hides if h == "none")
+
+    @property
+    def hidden_halos(self) -> int:
+        """SpMVs whose halo exchange overlaps interior compute."""
+        return sum(1 for h in self.halo_hides if h == "interior")
 
     @property
     def has_fused_body(self) -> bool:
@@ -687,7 +791,9 @@ def _cg_step(ops, state):
 
 register_method(MethodDef(
     name="cg", vectors=("x", "r", "p"), scalars=("rr",), res_scalar="rr",
-    init=_cg_init, step=_cg_step))
+    init=_cg_init, step=_cg_step,
+    reduction_hides=("none", "vec"), spmvs_per_iter=1, spd_required=True,
+    description="classical conjugate gradient (2 blocking reductions)"))
 
 
 def _cg_nb_init(ops, x0):
@@ -727,7 +833,9 @@ register_method(MethodDef(
     name="cg_nb", vectors=("x", "r", "p", "Ap"), scalars=("an", "ad"),
     res_scalar="an", init=_cg_nb_init, step=_cg_nb_step,
     finalize=_cg_nb_finalize, variant_of="cg",
-    guard=_nonpositive_guard(5)))       # ad = p·Ap: negative curvature
+    guard=_nonpositive_guard(5),        # ad = p·Ap: negative curvature
+    reduction_hides=("spmv", "vec"), spmvs_per_iter=1, spd_required=True,
+    description="nonblocking CG (Alg. 1): both reductions off the critical path"))
 
 
 def _pcg_init(ops, x0):
@@ -761,7 +869,14 @@ register_method(MethodDef(
     name="pcg", vectors=("x", "r", "p"), scalars=("rz", "rr"),
     res_scalar="rr", init=_pcg_init, step=_pcg_step,
     variant_of="cg", accepts_precond=True,
-    guard=_nonpositive_guard(3)))       # rz = r·M⁻¹r: M or A not SPD
+    guard=_nonpositive_guard(3),        # rz = r·M⁻¹r: M or A not SPD
+    reduction_hides=("none", "none", "vec"), spmvs_per_iter=1,
+    spd_required=True,
+    allreduces_per_iter=2,       # the (r·z, r·r) pair rides ONE psum (dot2)
+    precond_applies_per_iter=1,
+    description="preconditioned CG (repro.precond): p·Ap and r·z block, "
+                "r·r feeds only the check; +0 reductions from the "
+                "built-in preconditioners"))
 
 
 def _cg_merged_scalars(gamma, delta, gamma_prev, alpha_prev):
@@ -854,7 +969,10 @@ register_method(MethodDef(
     fused_kernels=("cg_body", "spmv_dots"),
     fused_init=_cg_merged_fused_init, fused_step=_cg_merged_fused_step,
     guard=_nonpositive_guard(6),        # delta = r·Ar: A not SPD
-    refresh=_cg_merged_refresh, refresh_spmvs=3))
+    refresh=_cg_merged_refresh, refresh_spmvs=3,
+    reduction_hides=("none",), spmvs_per_iter=1, spd_required=True,
+    description="Chronopoulos–Gear CG: all dots in ONE stacked psum "
+                "(Saad recurrence for p·Ap)"))
 
 
 def _pcg_merged_init(ops, x0):
@@ -924,7 +1042,10 @@ register_method(MethodDef(
     fused_kernels=("pcg_body", "spmv_dots3"),
     fused_init=_pcg_merged_init, fused_step=_pcg_merged_fused_step,
     guard=_pcg_merged_guard,
-    refresh=_pcg_merged_refresh, refresh_spmvs=3))
+    refresh=_pcg_merged_refresh, refresh_spmvs=3,
+    reduction_hides=("none",), spmvs_per_iter=1, spd_required=True,
+    precond_applies_per_iter=1,
+    description="merged-reduction PCG (Chronopoulos–Gear with M)"))
 
 
 def _cg_pipe_init(ops, x0):
@@ -995,7 +1116,10 @@ register_method(MethodDef(
     variant_of="cg", reduce_hide="pipelined",
     fused_kernels=("spmv_dots3", "pipe_body"),
     fused_init=_cg_pipe_init, fused_step=_cg_pipe_fused_step,
-    refresh=_cg_pipe_refresh, refresh_spmvs=4))
+    refresh=_cg_pipe_refresh, refresh_spmvs=4,
+    reduction_hides=("pipe",), spmvs_per_iter=1, spd_required=True,
+    description="Ghysels–Vanroose pipelined CG: the ONE stacked psum "
+                "overlaps the SpMV"))
 
 
 def _pcg_pipe_init(ops, x0):
@@ -1067,7 +1191,10 @@ register_method(MethodDef(
     variant_of="pcg", reduce_hide="pipelined", accepts_precond=True,
     fused_kernels=("fused_dots", "ppipe_body"),
     fused_init=_pcg_pipe_init, fused_step=_pcg_pipe_fused_step,
-    refresh=_pcg_pipe_refresh, refresh_spmvs=4))
+    refresh=_pcg_pipe_refresh, refresh_spmvs=4,
+    reduction_hides=("pipe",), spmvs_per_iter=1, spd_required=True,
+    precond_applies_per_iter=1,
+    description="pipelined PCG: the stacked psum overlaps M-apply + SpMV"))
 
 
 # =============================================================================
@@ -1103,7 +1230,9 @@ register_method(MethodDef(
     name="bicgstab", vectors=("x", "r", "rhat", "p"),
     scalars=("rho", "rr"), res_scalar="rr",
     init=_bicgstab_init, step=_bicgstab_step,
-    guard=_rho_underflow_guard(4, 5)))
+    guard=_rho_underflow_guard(4, 5),
+    reduction_hides=("none", "none", "vec"), spmvs_per_iter=2,
+    description="classical BiCGStab (3 blocking reductions)"))
 
 
 def _pbicgstab_step(ops, state):
@@ -1136,7 +1265,10 @@ register_method(MethodDef(
     scalars=("rho", "rr"), res_scalar="rr",
     init=_bicgstab_init, step=_pbicgstab_step,
     variant_of="bicgstab", accepts_precond=True,
-    guard=_rho_underflow_guard(4, 5)))
+    guard=_rho_underflow_guard(4, 5),
+    reduction_hides=("none", "none", "vec"), spmvs_per_iter=2,
+    precond_applies_per_iter=2,
+    description="right-preconditioned BiCGStab (true-residual stopping)"))
 
 
 def _bicgstab_b1_init(ops, x0):
@@ -1186,7 +1318,9 @@ register_method(MethodDef(
     name="bicgstab_b1", vectors=("x", "r", "p", "rhat"),
     scalars=("an", "beta_rr"), res_scalar="beta_rr",
     init=_bicgstab_b1_init, step=_bicgstab_b1_step,
-    variant_of="bicgstab", params=("eps_restart",)))
+    variant_of="bicgstab", params=("eps_restart",),
+    reduction_hides=("none", "vec", "vec"), spmvs_per_iter=2,
+    description="BiCGStab one-blocking (Alg. 2) with restart"))
 
 
 def _merged_bicgstab_matvec(ops, preconditioned: bool):
@@ -1319,7 +1453,10 @@ register_method(MethodDef(
     fused_init=_make_bicgstab_merged_init(False),
     fused_step=_make_bicgstab_merged_fused_step(False),
     guard=_rho_underflow_guard(8, 10),
-    refresh=_make_bicgstab_merged_refresh(False), refresh_spmvs=5))
+    refresh=_make_bicgstab_merged_refresh(False), refresh_spmvs=5,
+    reduction_hides=("none",), spmvs_per_iter=2,
+    description="single-reduction BiCGStab: nine dots, ONE stacked psum "
+                "(Cools–Vanroose recurrences)"))
 
 register_method(MethodDef(
     name="pbicgstab_merged",
@@ -1334,7 +1471,11 @@ register_method(MethodDef(
     fused_init=_make_bicgstab_merged_init(True),
     fused_step=_make_bicgstab_merged_fused_step(True),
     guard=_rho_underflow_guard(8, 10),
-    refresh=_make_bicgstab_merged_refresh(True), refresh_spmvs=5))
+    refresh=_make_bicgstab_merged_refresh(True), refresh_spmvs=5,
+    reduction_hides=("none",), spmvs_per_iter=2,
+    precond_applies_per_iter=2,
+    description="right-preconditioned single-reduction BiCGStab "
+                "(merged core on A∘M⁻¹, true-residual stopping)"))
 
 
 # =============================================================================
@@ -1359,7 +1500,9 @@ def _jacobi_step(ops, state):
 register_method(MethodDef(
     name="jacobi", vectors=("x", "r"), scalars=("rr",), res_scalar="rr",
     init=_jacobi_init, step=_jacobi_step, stationary=True,
-    default_maxiter=1000))
+    default_maxiter=1000,
+    reduction_hides=("none",), spmvs_per_iter=1,
+    description="x += D^-1 r; 1 SpMV + 1 blocking residual reduction"))
 
 
 def _plane_sweep(A, b, x, *, forward: bool) -> jax.Array:
@@ -1426,12 +1569,22 @@ def _gauss_seidel_rb_step(ops, state):
     return (x, rr)
 
 
+# The sweeps read their halos at the first plane/colour, so neither SpMV's
+# exchange hides (halo_hides "none"), and each half-sweep exchanges its own.
 register_method(MethodDef(
     name="gauss_seidel_rb", vectors=("x",), scalars=("rr",),
     res_scalar="rr", init=_stationary_init, step=_gauss_seidel_rb_step,
-    stationary=True, default_maxiter=1000))
+    stationary=True, default_maxiter=1000,
+    reduction_hides=("none",), spmvs_per_iter=2,
+    halo_hides=("none", "none"),
+    halo_exchanges_per_iter=5,   # 4 colour half-sweeps + the residual matvec
+    description="red-black coloured symmetric Gauss-Seidel (§3.4)"))
 
 register_method(MethodDef(
     name="gauss_seidel", vectors=("x",), scalars=("rr",),
     res_scalar="rr", init=_stationary_init, step=_gauss_seidel_step,
-    variant_of="gauss_seidel_rb", stationary=True, default_maxiter=1000))
+    variant_of="gauss_seidel_rb", stationary=True, default_maxiter=1000,
+    reduction_hides=("none",), spmvs_per_iter=2,
+    halo_hides=("none", "none"),
+    halo_exchanges_per_iter=3,   # fwd + bwd plane sweeps + residual matvec
+    description="relaxed tasked symmetric GS (§3.4 Code 4, TPU adaptation)"))

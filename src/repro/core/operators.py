@@ -315,7 +315,7 @@ def touched_elements_per_iter(method: str, nbar: int) -> int:
         # preconditioned forms: the baseline's traffic + the z (pcg) or
         # phat/shat (pbicgstab) vector updates; the preconditioner apply's
         # own traffic is accounted separately (Preconditioner.
-        # touched_elements_per_apply × SolverSpec.precond_applies_per_iter)
+        # touched_elements_per_apply × MethodDef.precond_applies_per_iter)
         "pcg": 16 + nbar,
         "pbicgstab": 27 + 2 * nbar,
         # reduction-hiding variants (PR 4), same accounting (3 per

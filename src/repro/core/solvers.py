@@ -7,8 +7,8 @@ derives the familiar solver functions
 
     cg(A, b, x0, *, tol=1e-6, maxiter=500, dot=None, norm_ref=None)
 
-from those definitions, so existing callers (and the paper-faithful
-``SOLVERS`` / ``VARIANT_OF`` tables) keep working unchanged.  The same
+from those definitions: ``SOLVERS`` holds one per registered method, and
+the module-level names (``cg``, ``bicgstab_b1``, …) are its entries.  The same
 definitions drive ``core.distributed.solve_shardmap`` /
 ``solve_step_shardmap`` and the fused Pallas path — the paper's design
 where the algorithm is written once and the parallelisation (MPI /
@@ -89,8 +89,7 @@ def make_solver(name: str) -> Callable:
     callable for one registered MethodDef (plus ``M=`` for the
     preconditioned methods and the definition's declared tuning knobs —
     e.g. ``eps_restart=`` for bicgstab_b1 — threaded through
-    ``Ops.params``).  This is the ``fn`` a registry entry for a new method
-    should point at (docs/API.md §"Authoring a new method").
+    ``Ops.params``).  ``SOLVERS`` holds one per registered method.
     """
     mdef = get_method(name)
 
@@ -119,41 +118,16 @@ def make_solver(name: str) -> Callable:
     return solver
 
 
-cg = make_solver("cg")
-cg_nb = make_solver("cg_nb")
-pcg = make_solver("pcg")
-cg_merged = make_solver("cg_merged")
-pcg_merged = make_solver("pcg_merged")
-cg_pipe = make_solver("cg_pipe")
-pcg_pipe = make_solver("pcg_pipe")
-bicgstab = make_solver("bicgstab")
-pbicgstab = make_solver("pbicgstab")
-bicgstab_b1 = make_solver("bicgstab_b1")
-bicgstab_merged = make_solver("bicgstab_merged")
-pbicgstab_merged = make_solver("pbicgstab_merged")
-jacobi = make_solver("jacobi")
-sym_gauss_seidel_relaxed = make_solver("gauss_seidel")
-sym_gauss_seidel_rb = make_solver("gauss_seidel_rb")
+#: one solver callable per registered MethodDef
+SOLVERS: dict[str, Callable] = {name: make_solver(name) for name in METHODS}
 
-SOLVERS: dict[str, Callable] = {
-    "jacobi": jacobi,
-    "gauss_seidel": sym_gauss_seidel_relaxed,
-    "gauss_seidel_rb": sym_gauss_seidel_rb,
-    "cg": cg,
-    "cg_nb": cg_nb,
-    "cg_merged": cg_merged,
-    "cg_pipe": cg_pipe,
-    "pcg": pcg,
-    "pcg_merged": pcg_merged,
-    "pcg_pipe": pcg_pipe,
-    "bicgstab": bicgstab,
-    "bicgstab_b1": bicgstab_b1,
-    "bicgstab_merged": bicgstab_merged,
-    "pbicgstab": pbicgstab,
-    "pbicgstab_merged": pbicgstab_merged,
-}
-
-#: methods refining a classical baseline mapped to that baseline — derived
-#: from the MethodDefs (single source); the registry cross-checks it.
-VARIANT_OF = {name: m.variant_of for name, m in METHODS.items()
-              if m.variant_of is not None}
+cg = SOLVERS["cg"]
+cg_nb = SOLVERS["cg_nb"]
+pcg = SOLVERS["pcg"]
+cg_merged = SOLVERS["cg_merged"]
+bicgstab = SOLVERS["bicgstab"]
+pbicgstab = SOLVERS["pbicgstab"]
+bicgstab_b1 = SOLVERS["bicgstab_b1"]
+jacobi = SOLVERS["jacobi"]
+sym_gauss_seidel_relaxed = SOLVERS["gauss_seidel"]
+sym_gauss_seidel_rb = SOLVERS["gauss_seidel_rb"]

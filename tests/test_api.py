@@ -21,7 +21,8 @@ from repro.api import (
     variant_pairs,
 )
 from repro.core.problems import make_problem
-from repro.core.solvers import SOLVERS, VARIANT_OF, LocalOp
+from repro.core.methods import METHODS
+from repro.core.solvers import SOLVERS, LocalOp
 
 pytestmark = pytest.mark.usefixtures("f64")
 
@@ -188,37 +189,15 @@ def test_hpcg_config_wires_into_facade():
 
 
 def test_registry_subsumes_core_dicts():
-    assert set(REGISTRY) == set(SOLVERS)
+    assert REGISTRY is METHODS
+    assert set(SOLVERS) == set(METHODS)
     assert solver_names() == sorted(SOLVERS)
-    for variant, base in VARIANT_OF.items():
+    assert variant_pairs() == sorted(
+        (m.variant_of, name) for name, m in METHODS.items()
+        if m.variant_of is not None)
+    for base, variant in variant_pairs():
         assert get_solver(variant).variant_of == base
-    assert (base_variant := dict(variant_pairs())) and all(
-        base in REGISTRY for base in base_variant)
-
-
-def test_registry_consistency_check_raises_real_exceptions():
-    """The import-time registry/core cross-check must raise (not assert:
-    asserts vanish under ``python -O``, silently disabling the guard)."""
-    import dataclasses
-    from repro.api import RegistryConsistencyError, check_consistent_with_core
-
-    check_consistent_with_core()                     # current state is good
-    missing = dict(REGISTRY)
-    missing.pop("cg")
-    with pytest.raises(RegistryConsistencyError, match="core-only"):
-        check_consistent_with_core(registry=missing)
-    wrong_fn = dict(REGISTRY)
-    wrong_fn["cg"] = dataclasses.replace(REGISTRY["cg"],
-                                         fn=lambda *a, **k: None)
-    with pytest.raises(RegistryConsistencyError, match="registered fn"):
-        check_consistent_with_core(registry=wrong_fn)
-    with pytest.raises(RegistryConsistencyError, match="variant_of"):
-        check_consistent_with_core(variant_of={"cg_nb": "bicgstab"})
-    # and the guard really is exception-based, not assert-based: it must
-    # keep firing when Python strips asserts (compile with optimize=2)
-    import inspect
-    src = inspect.getsource(check_consistent_with_core)
-    assert "assert " not in src
+        assert base in REGISTRY
 
 
 def test_registry_barrier_metadata_matches_paper():

@@ -4,7 +4,7 @@ real registry/kernel table runs clean.
 
 Three injections, one per pass:
 
-* a ``SolverSpec`` whose ``allreduces_per_iter`` understates what the HLO
+* a ``MethodDef`` whose ``allreduces_per_iter`` understates what the HLO
   contains → the comms comparator flags the ``all-reduce`` count;
 * a ``MethodDef`` whose step branches (Python ``if``) on a traced scalar →
   the AST lint flags ``traced_branch``;
@@ -28,14 +28,8 @@ from repro.analysis.lint_kernels import (
     check_kernels,
 )
 from repro.analysis.lint_methods import check_method, check_methods
-from repro.api.registry import (
-    REGISTRY,
-    RegistryConsistencyError,
-    SolverSpec,
-    method_field_diff,
-    register_solver,
-)
-from repro.core.methods import METHODS, MethodDef
+from repro.api.registry import REGISTRY
+from repro.core.methods import MethodDef
 
 
 def _fake_measured(registry, mesh="1d", halo="concat"):
@@ -137,7 +131,8 @@ def _branchy_step(ops, state):
 
 _BRANCHY = MethodDef(
     name="_audit_branchy", vectors=("x",), scalars=("res2",),
-    res_scalar="res2", init=_branchy_init, step=_branchy_step)
+    res_scalar="res2", init=_branchy_init, step=_branchy_step,
+    reduction_hides=("none",), spmvs_per_iter=1)
 
 
 def test_traced_branch_flagged():
@@ -193,43 +188,6 @@ def test_real_kernel_table_clean():
     # and the real table stays under budget with honest margins
     for spec in KERNEL_TABLE:
         assert spec.vmem_bytes <= VMEM_BUDGET_BYTES, spec.name
-
-
-# -----------------------------------------------------------------------------
-# RegistryConsistencyError renders an expected-vs-actual field diff
-# -----------------------------------------------------------------------------
-
-def test_registry_consistency_error_prints_field_diff():
-    toy = MethodDef(
-        name="_audit_toy", vectors=("x",), scalars=("res2",),
-        res_scalar="res2", init=_branchy_init,
-        step=lambda ops, state: (state[0], state[1] * 0.5))
-    METHODS[toy.name] = toy
-    try:
-        with pytest.raises(RegistryConsistencyError) as exc:
-            register_solver(SolverSpec(
-                name=toy.name, fn=lambda *a, **k: None,
-                reduction_hides=("none",), spmvs_per_iter=1,
-                stationary=True, accepts_precond=True))   # mdef says False/False
-        msg = str(exc.value)
-        assert "drifted from its MethodDef" in msg
-        # the aligned table: header row + one row per mismatched field
-        assert "registry" in msg and "derived" in msg
-        assert "stationary" in msg and "accepts_precond" in msg
-        assert "True" in msg and "False" in msg
-        assert toy.name not in REGISTRY          # rejected, not registered
-    finally:
-        METHODS.pop(toy.name, None)
-        REGISTRY.pop(toy.name, None)
-
-
-def test_method_field_diff_rows():
-    spec = REGISTRY["cg"]
-    assert method_field_diff(spec, METHODS["cg"]) == []
-    diffs = method_field_diff(spec, METHODS["cg_merged"])
-    assert any(d.field == "reduce_hide" for d in diffs)
-    d = next(d for d in diffs if d.field == "reduce_hide")
-    assert "registry declares" in str(d) and "derived says" in str(d)
 
 
 # -----------------------------------------------------------------------------

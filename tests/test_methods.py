@@ -3,16 +3,17 @@
 Covers the pieces the refactor introduced: the declared-state machinery
 (init matches the layout, res_scalar resolves), the generic ``run_method``
 driver (a brand-new method authored per docs/API.md §"Authoring a new
-method" solves the system without touching any driver), the registry's
-metadata-vs-definition cross-validation, and the clear-error paths.
+method" solves the system without touching any driver), the checks on the
+declared communication metadata, and the clear-error paths.
 """
+
+import dataclasses
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.api.registry import (REGISTRY, RegistryConsistencyError,
-                                SolverSpec, _validate_against_method)
+from repro.api.registry import REGISTRY
 from repro.core.methods import (METHODS, MethodDef, Ops, get_method,
                                 method_names, register_method, run_method)
 from repro.core.problems import make_problem
@@ -43,7 +44,7 @@ def test_init_and_step_match_declared_layout(name):
     # one registered solver callable per definition, and vice versa
     assert set(METHODS) == set(SOLVERS) == set(REGISTRY)
     assert SOLVERS[name].method_def is mdef
-    assert REGISTRY[name].method_def is mdef
+    assert REGISTRY[name] is mdef
 
 
 def test_solver_wrappers_reject_unknown_kwargs():
@@ -71,62 +72,55 @@ def test_get_method_unknown_name_lists_registry():
 
 def test_method_def_validates_declarations():
     dummy = lambda ops, *a: a  # noqa: E731
+    comms = dict(reduction_hides=("none",), spmvs_per_iter=1)
     with pytest.raises(ValueError, match="res_scalar"):
         MethodDef(name="bad", vectors=("x",), scalars=("rr",),
-                  res_scalar="nope", init=dummy, step=dummy)
+                  res_scalar="nope", init=dummy, step=dummy, **comms)
     with pytest.raises(ValueError, match="fused"):
         MethodDef(name="bad", vectors=("x",), scalars=("rr",),
                   res_scalar="rr", init=dummy, step=dummy,
-                  fused_kernels=("spmv_dots",))
+                  fused_kernels=("spmv_dots",), **comms)
 
 
 # -----------------------------------------------------------------------------
-# Registry metadata is cross-validated against the definitions
+# The declared communication metadata is checked at definition time
 # -----------------------------------------------------------------------------
 
-def test_registry_metadata_validated_against_method_def():
-    import dataclasses
-    spec = REGISTRY["pcg"]
-    mdef = METHODS["pcg"]
-    _validate_against_method(spec, mdef)            # current state is good
-    drifted = dataclasses.replace(spec, accepts_precond=False,
-                                  precond_applies_per_iter=0)
-    with pytest.raises(RegistryConsistencyError, match="accepts_precond"):
-        _validate_against_method(drifted, mdef)
-    drifted = dataclasses.replace(REGISTRY["cg_merged"], reduce_hide="none",
-                                  reduction_hides=("none", "none"))
-    with pytest.raises(RegistryConsistencyError, match="reduce_hide"):
-        _validate_against_method(drifted, METHODS["cg_merged"])
-    with pytest.raises(RegistryConsistencyError, match="fused_kernels"):
-        _validate_against_method(
-            dataclasses.replace(REGISTRY["cg_merged"], fused_kernels=()),
-            METHODS["cg_merged"])
+@pytest.mark.parametrize("base,override,match", [
+    ("cg", dict(halo_hides=("interior", "none")), "one entry per SpMV"),
+    ("cg", dict(precond_applies_per_iter=1),
+     "precond_applies_per_iter without accepts_precond"),
+    ("cg", dict(reduce_hide="stacked"), "unknown reduce_hide"),
+    ("cg_merged", dict(reduction_hides=("none", "none")),
+     "ONE stacked reduction per iteration"),
+    ("cg_merged", dict(reduce_hide="pipelined"),
+     "hides behind the next SpMV"),
+    ("cg_merged", dict(allreduces_per_iter=2), "claims ONE stacked"),
+    ("cg", dict(allreduces_per_iter=3), "exceeds the declared logical"),
+    ("gauss_seidel", dict(halo_exchanges_per_iter=1),
+     "below spmvs_per_iter"),
+], ids=["halo_hides_len", "precond_applies", "reduce_hide_vocab",
+        "merged_many_reductions", "pipelined_not_pipe",
+        "stacked_allreduces", "allreduces_exceed", "halo_exchanges_low"])
+def test_method_def_rejects_inconsistent_metadata(base, override, match):
+    with pytest.raises(ValueError, match=match):
+        dataclasses.replace(METHODS[base], name="bad", **override)
 
 
-def test_fused_kernels_require_fused_body_and_pallas_hooks():
-    """PR 10 regression: a spec may only advertise ``fused_kernels`` when the
-    MethodDef actually carries a fused body, and every advertised hook must
-    exist on PallasOp — otherwise session routing would silently fall back."""
-    import dataclasses
-    # spec claims fused kernels, but the plain-cg MethodDef has no fused body
-    spec = dataclasses.replace(REGISTRY["cg"], fused_kernels=("cg_body",))
-    with pytest.raises(RegistryConsistencyError, match="no fused body"):
-        _validate_against_method(spec, METHODS["cg"])
-    # spec/mdef agree on a hook name that PallasOp does not implement
-    mdef = dataclasses.replace(METHODS["cg_merged"],
-                               fused_kernels=("not_a_hook",))
-    spec = dataclasses.replace(REGISTRY["cg_merged"],
-                               fused_kernels=("not_a_hook",))
-    with pytest.raises(RegistryConsistencyError, match="PallasOp"):
-        _validate_against_method(spec, mdef)
+FUSED = [n for n in sorted(METHODS) if METHODS[n].fused_kernels]
 
 
-def test_register_solver_requires_a_method_def():
-    from repro.api.registry import register_solver
-    with pytest.raises(RegistryConsistencyError, match="no MethodDef"):
-        register_solver(SolverSpec(
-            name="sor_unregistered", fn=lambda *a, **k: None,
-            reduction_hides=("none",), spmvs_per_iter=1))
+@pytest.mark.parametrize("name", FUSED)
+def test_fused_kernels_require_fused_body_and_pallas_hooks(name):
+    """A method may only advertise ``fused_kernels`` that are real
+    ``PallasOp`` hooks, with a fused body beside them — otherwise session
+    routing would fail at trace time, or fall back silently."""
+    from repro.kernels.pallas_op import PallasOp
+    mdef = METHODS[name]
+    assert len(FUSED) == 6
+    assert mdef.fused_step is not None and mdef.fused_init is not None
+    for hook in mdef.fused_kernels:
+        assert callable(getattr(PallasOp, hook, None)), (name, hook)
 
 
 # -----------------------------------------------------------------------------
@@ -147,7 +141,8 @@ def _richardson_def(omega: float = 0.035) -> MethodDef:
 
     return MethodDef(name="richardson", vectors=("x", "r"), scalars=("rr",),
                      res_scalar="rr", init=init, step=step, stationary=True,
-                     default_maxiter=5000)
+                     default_maxiter=5000,
+                     reduction_hides=("none",), spmvs_per_iter=1)
 
 
 def test_toy_richardson_solves_via_generic_driver():

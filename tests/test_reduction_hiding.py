@@ -209,12 +209,12 @@ def test_fused_routing_is_capability_based():
     fused = SolverSession(prob, method="cg_merged",
                           options=SolverOptions(pallas=True))
     assert fused._use_fused_body()
-    assert fused.spec.has_fused_body
+    assert fused.mdef.has_fused_body
     # pallas=True on a non-fused method still swaps the SpMV kernel only
     plain = SolverSession(prob, method="cg",
                           options=SolverOptions(pallas=True))
     assert not plain._use_fused_body()
-    assert not plain.spec.has_fused_body
+    assert not plain.mdef.has_fused_body
 
 
 def test_fused_solve_matches_solver_loop():
@@ -309,16 +309,6 @@ def test_registry_reduction_hiding_metadata():
     assert REGISTRY["bicgstab_merged"].reduce_hide == "merged"
     for classic in ("cg", "cg_nb", "bicgstab", "bicgstab_b1"):
         assert REGISTRY[classic].reduce_hide == "none"
-
-
-def test_registry_rejects_inconsistent_reduce_hide():
-    import dataclasses
-    spec = REGISTRY["cg_merged"]
-    with pytest.raises(ValueError, match="ONE stacked reduction"):
-        dataclasses.replace(spec, name="bad",
-                            reduction_hides=("none", "none"))
-    with pytest.raises(ValueError, match="pipe"):
-        dataclasses.replace(spec, name="bad", reduce_hide="pipelined")
 
 
 def test_scaling_model_t_reduce_term():

@@ -79,10 +79,9 @@ def test_step_state_derived_from_method_def():
         ("rho", "alpha", "rr"))
     for method, spec in REGISTRY.items():
         vecs, scals = step_state_layout(method)
-        assert (vecs, scals) == (spec.method_def.vectors,
-                                 spec.method_def.scalars)
+        assert (vecs, scals) == (spec.vectors, spec.scalars)
         assert vecs[0] == "x"
-        assert spec.method_def.res_scalar in scals
+        assert spec.res_scalar in scals
 
 
 def test_unregistered_method_raises_with_known_list(mesh1):
