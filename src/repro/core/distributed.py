@@ -30,7 +30,8 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.methods import Ops, get_method, in_scope, local_dot, run_method
-from repro.core.operators import Stencil, interior_matvec, shell_assemble
+from repro.core.operators import (Stencil, interior_matvec, shell_assemble,
+                                  shell_slabs, slab_operand)
 from repro.core.problems import HPCGProblem
 from repro.core.solvers import SolveResult
 
@@ -87,6 +88,8 @@ class DistributedOp:
         # the slice-add apply LocalOp runs, so one block's arithmetic is the
         # same on one chip and on a mesh
         self._mv_padded = matvec_padded or stencil.matvec_padded
+        if not stencil.fuses_dot(matvec_padded):
+            self.matvec_dot = None        # Ops.matvec_dot: matvec, then dot
         if halo_mode not in HALO_MODES:
             raise ValueError(
                 f"unknown halo_mode {halo_mode!r}; options: {HALO_MODES}")
@@ -190,6 +193,14 @@ class DistributedOp:
         """
         return self._mv_padded(jnp.pad(x, 1))
 
+    def _overlap_split(self, x: jax.Array) -> tuple[int, ...]:
+        """The split dims of the interior/shell split; none where nothing is
+        decomposed or a block is one plane thick (no interior)."""
+        split = self.split_dims
+        if not split or min(x.shape[d] for d in split) < 2:
+            return ()
+        return split
+
     def _matvec_overlap(self, x: jax.Array) -> jax.Array:
         """Overlapped halo-exchange SpMV (the paper's task-based split).
 
@@ -203,14 +214,49 @@ class DistributedOp:
         Solver results are bit-for-bit identical to the concat/scatter
         modes (tests/test_halo_overlap.py).
         """
-        split = self.split_dims
-        if not split or min(x.shape[d] for d in split) < 2:
-            # nothing decomposed (or degenerate 1-plane blocks: no interior)
-            return self._mv_padded(self._pad_exchange_concat(x))
+        split = self._overlap_split(x)
         xp = self._pad_exchange_concat(x)
+        if not split:
+            return self._mv_padded(xp)
         y_int = lax.optimization_barrier(
             interior_matvec(self._mv_padded, x, split))
         return shell_assemble(self._mv_padded, xp, y_int, split)
+
+    def matvec_dot(self, x: jax.Array) -> tuple:
+        """``(A x, x·A x)`` with the dot's one psum under ``repro.reduce``.
+
+        The built-in box apply computes the local partial in its own pass
+        (``Stencil.matvec_dot_padded``), split as under ``overlap``: the
+        interior's partial rides the interior's barrier and each shell slab
+        adds its own, in a fixed order.  The fork-join modes run the same
+        split on their exchanged operand, so every halo mode gives the same
+        bits and still waits for every received plane.  ``None`` for any
+        other apply.
+        """
+        split = self._overlap_split(x)
+        if self.halo_mode == "overlap":
+            xp = self._pad_exchange_concat(x)
+        else:
+            # fork-join: the interior reads the block out of the exchanged
+            # operand, so it too waits for every received plane
+            xp = lax.optimization_barrier(self.pad_exchange(x))
+            x = xp[1:-1, 1:-1, 1:-1]
+        if not split:
+            y, xy = self._pair_padded(xp)
+        else:
+            y, xy = lax.optimization_barrier(
+                interior_matvec(self._pair_padded, x, split))
+        for d, lo, hi in shell_slabs(x.shape, split):
+            (y_lo, xy_lo), (y_hi, xy_hi) = (
+                self._pair_padded(slab_operand(xp, box)) for box in (lo, hi))
+            y = jnp.concatenate([y_lo, y, y_hi], axis=d)
+            with jax.named_scope("repro.reduce"):
+                xy = xy + xy_lo + xy_hi
+        (xy,) = self.sum_partials(xy)
+        return y, xy
+
+    def _pair_padded(self, xp: jax.Array) -> tuple:
+        return self.stencil.matvec_dot_padded(xp, local_dot)
 
     # --- global reductions (the paper's MPI_Allreduce) -----------------------
     def dot(self, a: jax.Array, b: jax.Array) -> jax.Array:

@@ -205,6 +205,13 @@ def in_scope(name: str) -> Callable:
     return wrap
 
 
+def _own_dot(A, dot) -> bool:
+    """Whether ``dot`` is the operator's own, or none was given: only then
+    may the operator's fused reductions (``dotn``, ``matvec_dot``) stand in
+    for it."""
+    return dot is None or getattr(dot, "__self__", None) is A
+
+
 def _stacked_dot(A, dot):
     """The fused-reduction hook of the merged/pipelined variants.
 
@@ -215,7 +222,7 @@ def _stacked_dot(A, dot):
     variants.  A foreign ``dot`` override (``SolverOptions.dot``) falls back
     to per-pair calls, preserving its semantics at the cost of the fusion.
     """
-    if dot is None or getattr(dot, "__self__", None) is A:
+    if _own_dot(A, dot):
         dn = getattr(A, "dotn", None)
         if dn is not None:
             return dn
@@ -237,8 +244,8 @@ def _hist_init(maxiter: int, v0, dtype) -> jax.Array:
 #: (``repro.analysis.lint_methods``) and the humans reading this file agree.
 #: A method body calling anything else is coupling itself to one backend.
 OPS_PROTOCOL = frozenset({
-    "A", "b", "M", "dot", "dot2", "dotn", "matvec", "diag", "norm_ref",
-    "params",
+    "A", "b", "M", "dot", "dot2", "dotn", "matvec", "matvec_dot", "diag",
+    "norm_ref", "params",
 })
 
 #: what a MethodDef may touch on the operator itself (``ops.A`` — the
@@ -247,8 +254,9 @@ OPS_PROTOCOL = frozenset({
 #: hook a ``fused_step`` body targets (``PallasOp`` supplies them — one per
 #: single-pass Pallas kernel of the reduction-hiding family).
 OPERATOR_PROTOCOL = frozenset({
-    "matvec", "matvec_local", "pad_exchange", "diag", "stencil", "dot",
-    "dot2", "dotn", "sum_partials", "split_dims", "base", "spmv_dots",
+    "matvec", "matvec_dot", "matvec_local", "pad_exchange", "diag",
+    "stencil", "dot", "dot2", "dotn", "sum_partials", "split_dims", "base",
+    "spmv_dots",
     "cg_body", "spmv_dots3", "fused_dots", "pipe_body", "pcg_body",
     "ppipe_body", "bicgstab_spmv_dots", "bicgstab_update1",
     "bicgstab_spmv_update",
@@ -263,11 +271,14 @@ class Ops:
     global reduction (``DistributedOp.dot`` = one psum) when it has one,
     else :func:`local_dot`; ``dotn`` stacks any number of dot products into
     ONE collective where the operator supports it (see :func:`_stacked_dot`).
+    ``matvec_dot`` is ``(A p, p·A p)`` from the operator's own hook under
+    the same rule: a foreign ``dot`` gets ``matvec`` and then ``dot``.
     ``norm_ref=None`` resolves to ``||b||`` via ``dot`` (the relative
     criterion); the paper's absolute HPCCG criterion is ``norm_ref=1.0``.
     """
 
-    __slots__ = ("A", "b", "M", "dot", "dotn", "norm_ref", "params")
+    __slots__ = ("A", "b", "M", "dot", "dotn", "norm_ref", "params",
+                 "_matvec_dot")
 
     def __init__(self, A, b, *, M=None, dot=None, norm_ref=None,
                  params: dict | None = None):
@@ -278,6 +289,8 @@ class Ops:
         reduce = in_scope("repro.reduce")
         self.dot = reduce(dot if dot is not None else (own or local_dot))
         self.dotn = reduce(_stacked_dot(A, dot))
+        self._matvec_dot = (getattr(A, "matvec_dot", None)
+                            if _own_dot(A, dot) else None)
         self.params = params or {}
         if norm_ref is None:
             norm_ref = jnp.sqrt(self.dot(b, b))
@@ -286,6 +299,19 @@ class Ops:
     def matvec(self, x: jax.Array) -> jax.Array:
         with jax.named_scope("repro.matvec"):
             return self.A.matvec(x)
+
+    def matvec_dot(self, p: jax.Array) -> tuple:
+        """``(A p, p·A p)``: the apply and the global dot of its operand
+        with the result, for CG's step.  The operator's hook fences the
+        local partial with the apply, so XLA can reduce it in the apply's
+        pass; the dot's own operations and its psum stay ``repro.reduce``'s
+        (a fusion of the two is billed to ``repro.matvec``, by most of its
+        instructions)."""
+        if self._matvec_dot is None:
+            Ap = self.matvec(p)
+            return Ap, self.dot(p, Ap)
+        with jax.named_scope("repro.matvec"):
+            return self._matvec_dot(p)
 
     def dot2(self, a, b, c, d) -> tuple:
         """Two dot products in ONE collective (the paper fuses scalar pairs
@@ -778,8 +804,7 @@ def _cg_init(ops, x0):
 def _cg_step(ops, state):
     """Classical CG (HPCCG reference): 2 blocking reductions."""
     x, r, p, rr = state
-    Ap = ops.matvec(p)
-    pAp = ops.dot(p, Ap)              # blocking: feeds alpha immediately
+    Ap, pAp = ops.matvec_dot(p)       # blocking: feeds alpha immediately
     alpha = rr / pAp
     x = x + alpha * p
     r = r - alpha * Ap
@@ -853,8 +878,7 @@ def _pcg_step(ops, state):
     iteration counts are comparable with ``cg`` at the same tolerance.
     With ``M = I`` this is arithmetically identical to ``cg``."""
     x, r, p, rz, rr = state
-    Ap = ops.matvec(p)
-    pAp = ops.dot(p, Ap)              # blocking: feeds alpha immediately
+    Ap, pAp = ops.matvec_dot(p)       # blocking: feeds alpha immediately
     alpha = rz / pAp
     x = x + alpha * p
     r = r - alpha * Ap

@@ -25,7 +25,7 @@ sparse matrix) and doubles as the cross-check oracle for the stencil path.
 from __future__ import annotations
 
 import dataclasses
-from typing import Sequence
+from typing import Callable, Sequence
 
 import numpy as np
 
@@ -102,6 +102,12 @@ class Stencil:
         return (len(self.offsets) == 26
                 and set(self.offsets) == set(_offsets_27pt()))
 
+    def _box_apply(self, xp: jax.Array) -> jax.Array:
+        """The box stencil's apply, unfenced:
+        ``(diag - off_coeff) * x + off_coeff * box(x)``."""
+        return ((self.diag - self.off_coeff) * xp[1:-1, 1:-1, 1:-1]
+                + self.off_coeff * _box_sum(xp))
+
     def matvec_padded(self, xp: jax.Array) -> jax.Array:
         """Apply to a halo-padded array ``(nx+2, ny+2, nz+2)`` -> ``(nx, ny, nz)``.
 
@@ -113,9 +119,7 @@ class Stencil:
         result (CG's r update), outside the apply's pass.
         """
         if self.is_box:
-            q = ((self.diag - self.off_coeff) * xp[1:-1, 1:-1, 1:-1]
-                 + self.off_coeff * _box_sum(xp))
-            return jax.lax.optimization_barrier(q)
+            return jax.lax.optimization_barrier(self._box_apply(xp))
         nx, ny, nz = xp.shape[0] - 2, xp.shape[1] - 2, xp.shape[2] - 2
         acc = self.diag * xp[1:-1, 1:-1, 1:-1]
         for dx, dy, dz in self.offsets:
@@ -123,6 +127,29 @@ class Stencil:
                 xp, (1 + dx, 1 + dy, 1 + dz), (1 + dx + nx, 1 + dy + ny, 1 + dz + nz)
             )
         return acc
+
+    def fuses_dot(self, matvec_padded: Callable | None = None) -> bool:
+        """Whether an operator's apply can take a dot into its own pass
+        (``matvec_dot_padded``): the built-in box apply, where the operator
+        was given no ``matvec_padded`` of its own."""
+        return matvec_padded is None and self.is_box
+
+    def matvec_dot_padded(self, xp: jax.Array, dot: Callable) -> tuple:
+        """A box stencil's apply with the local dot of its operand and
+        result: ``(q, dot(x, q))`` with ``x`` the interior of ``xp``.
+
+        One barrier fences the pair, so the dot can be computed in the pass
+        that writes ``q`` (CG's ``p·Ap``) and ``q`` is still one array, as
+        under ``matvec_padded``'s barrier.  The dot's own operations are
+        scoped ``repro.reduce``: where XLA fuses them into the apply's pass
+        the fusion is the apply's (most of its instructions), and where it
+        keeps them a pass of their own that pass stays ``repro.reduce``'s.
+        Only for ``is_box`` (``fuses_dot``).
+        """
+        q = self._box_apply(xp)
+        with jax.named_scope("repro.reduce"):
+            pq = dot(xp[1:-1, 1:-1, 1:-1], q)
+        return jax.lax.optimization_barrier((q, pq))
 
     def matvec(self, x: jax.Array) -> jax.Array:
         """Apply to an unpadded grid array ``(nx, ny, nz)`` with zero boundary."""
@@ -171,15 +198,53 @@ class Stencil:
 # (asserted by tests/test_halo_overlap.py on 7pt/27pt × 1-D/3-D layouts).
 
 def interior_matvec(mv_padded, x: jax.Array,
-                    split_dims: Sequence[int]) -> jax.Array:
+                    split_dims: Sequence[int]):
     """Apply the stencil to the halo-independent interior of a local block.
 
     ``x`` is the UNPADDED local block.  Along each dim in ``split_dims`` the
     block itself provides the one-cell support of its interior (output extent
     ``n-2``); unsplit dims get the usual zero halo (physical boundary).
+    Returns what ``mv_padded`` returns.
     """
     pad = [(0, 0) if d in split_dims else (1, 1) for d in range(3)]
     return mv_padded(jnp.pad(x, pad))
+
+
+def shell_slabs(shape: Sequence[int], split_dims: Sequence[int]) -> list:
+    """The boundary shell of a local block of ``shape`` in assembly order:
+    ``(dim, lo box, hi box)`` per split dim, outermost last, each box the
+    ``(starts, limits)`` of the slab's output cells.
+
+    Slabs span the still-interior extent of the split dims assembled after
+    them, so edge/corner cells belong to exactly one slab.  A slab's
+    operand is its box widened by the halo: ``slab_operand``.
+    """
+    out, done = [], set()
+    for d in sorted(split_dims, reverse=True):
+        boxes = []
+        for s in (0, shape[d] - 1):
+            starts, limits = [], []
+            for e, n in enumerate(shape):
+                if e == d:                     # one output plane
+                    starts.append(s)
+                    limits.append(s + 1)
+                elif e in split_dims and e not in done:
+                    starts.append(1)           # dim still at interior extent
+                    limits.append(n - 1)
+                else:
+                    starts.append(0)           # assembled/unsplit: full extent
+                    limits.append(n)
+            boxes.append((tuple(starts), tuple(limits)))
+        out.append((d, *boxes))
+        done.add(d)
+    return out
+
+
+def slab_operand(xp: jax.Array, box: tuple) -> jax.Array:
+    """The padded operand of the output cells ``box``: their 3x3x3
+    neighbourhoods in the exchanged padded array ``xp``."""
+    starts, limits = box
+    return jax.lax.slice(xp, starts, [n + 2 for n in limits])
 
 
 def shell_assemble(mv_padded, xp: jax.Array, y_interior: jax.Array,
@@ -193,26 +258,10 @@ def shell_assemble(mv_padded, xp: jax.Array, y_interior: jax.Array,
     monolithic apply reads.
     """
     y = y_interior
-    done: set[int] = set()
-    for d in sorted(split_dims, reverse=True):
-        def slab(lo: bool) -> jax.Array:
-            starts, limits = [], []
-            for e in range(3):
-                pe = xp.shape[e]
-                if e == d:                     # 3 planes -> 1 output plane
-                    s = 0 if lo else pe - 3
-                    starts.append(s)
-                    limits.append(s + 3)
-                elif e in split_dims and e not in done:
-                    starts.append(1)           # dim still at interior extent
-                    limits.append(pe - 1)
-                else:
-                    starts.append(0)           # assembled/unsplit: full extent
-                    limits.append(pe)
-            return mv_padded(jax.lax.slice(xp, starts, limits))
-
-        y = jnp.concatenate([slab(True), y, slab(False)], axis=d)
-        done.add(d)
+    shape = [n - 2 for n in xp.shape]
+    for d, lo, hi in shell_slabs(shape, split_dims):
+        y = jnp.concatenate([mv_padded(slab_operand(xp, lo)), y,
+                             mv_padded(slab_operand(xp, hi))], axis=d)
     return y
 
 

@@ -56,6 +56,8 @@ class LocalOp:
     def __init__(self, stencil: Stencil, matvec_padded: Callable | None = None):
         self.stencil = stencil
         self._mv_padded = matvec_padded or stencil.matvec_padded
+        if not stencil.fuses_dot(matvec_padded):
+            self.matvec_dot = None        # Ops.matvec_dot: matvec, then dot
 
     @property
     def diag(self) -> float:
@@ -71,6 +73,11 @@ class LocalOp:
         """Zero-halo apply on the local block (block-Jacobi's inner operator).
         On a single device the block IS the domain, so this == matvec."""
         return self.matvec(x)
+
+    def matvec_dot(self, x: jax.Array) -> tuple:
+        """``(A x, x·A x)`` from the built-in box apply's one pass
+        (``Stencil.matvec_dot_padded``); ``None`` for any other apply."""
+        return self.stencil.matvec_dot_padded(self.pad_exchange(x), local_dot)
 
     def dotn(self, *pairs) -> tuple:
         """Stacked dot products — locally just the dots (no collective to

@@ -104,6 +104,20 @@ for m in ("cg", "bicgstab", "pcg",
     args = [arr] * (1 + len(vecs)) + [scal] * len(scals)
     txt = jax.jit(fn).lower(*args).compile().as_text()
     out[m] = count_collectives(txt).get("all-reduce", 0)
+# cg and pcg take p·Ap from the apply's pass (Ops.matvec_dot) in every
+# halo mode; the 7-point cross keeps its dot apart
+for st in ("27pt", "7pt"):
+    prob = make_problem((8, 8, 16), st)
+    for m in ("cg", "pcg"):
+        for hm in ("concat", "overlap"):
+            fn, layout = solve_step_shardmap(prob, m, mesh, halo_mode=hm)
+            sh = NamedSharding(mesh, layout.spec())
+            vecs, scals = step_state_layout(m)
+            arr = jax.ShapeDtypeStruct(prob.shape, prob.dtype, sharding=sh)
+            scal = jax.ShapeDtypeStruct((), prob.dtype)
+            args = [arr] * (1 + len(vecs)) + [scal] * len(scals)
+            txt = jax.jit(fn).lower(*args).compile().as_text()
+            out[f"{m}-{st}-{hm}"] = count_collectives(txt).get("all-reduce", 0)
 print(json.dumps(out))
 """
 
@@ -117,6 +131,15 @@ def test_classics_emit_multiple_allreduces(allreduce_counts):
     assert allreduce_counts["cg"] == 2
     assert allreduce_counts["bicgstab"] == 3
     assert allreduce_counts["pcg"] == 2      # p·Ap + the fused (r·z, r·r) pair
+
+
+@pytest.mark.parametrize("halo_mode", ["concat", "overlap"])
+@pytest.mark.parametrize("stencil", ["27pt", "7pt"])
+@pytest.mark.parametrize("method", ["cg", "pcg"])
+def test_matvec_dot_keeps_one_allreduce_per_dot(allreduce_counts, method,
+                                                 stencil, halo_mode):
+    """p·Ap from the apply's pass keeps its one psum: 2 per iteration."""
+    assert allreduce_counts[f"{method}-{stencil}-{halo_mode}"] == 2
 
 
 def test_merged_and_pipelined_emit_exactly_one_allreduce(allreduce_counts):

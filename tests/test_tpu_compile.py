@@ -125,6 +125,57 @@ def test_27pt_apply_with_dot_at_512_compiles_for_v5e(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2.2e9
 
 
+def _pAp_fusions(hlo: str) -> list[tuple[str, int]]:
+    """``(scope, slices)`` of each fusion that holds a reduce of CG's
+    ``p·Ap`` (scoped ``repro.reduce`` inside ``Ops.matvec_dot``'s
+    ``repro.matvec``): the scope ``op_scopes`` bills it to, and how many
+    slices of the apply's operand it holds besides."""
+    from repro.obs.scopes import op_scopes_from_text
+
+    comps, comp = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"^(?:ENTRY\s+)?%([\w.\-]+) .*\{\s*$", line)
+        if m:
+            comp = comps.setdefault(m.group(1), [])
+        elif comp is not None:
+            comp.append(line)
+    module = re.match(r"HloModule ([^\s,]+)", hlo).group(1)
+    scopes = op_scopes_from_text(hlo)
+    out = []
+    for name, lines in comps.items():
+        if not any(" reduce(" in ln and "repro.matvec/repro.reduce" in ln
+                   for ln in lines):
+            continue
+        slices = sum(" slice(" in ln for ln in lines)
+        for m in re.finditer(rf"^\s*(?:ROOT\s+)?%([\w.\-]+) = .*"
+                             rf"calls=%{re.escape(name)}\b", hlo, re.M):
+            out.append((scopes[(module, m.group(1))], slices))
+    return out
+
+
+@pytest.mark.parametrize("n,dtype", [(512, jnp.float32), (128, jnp.float64)])
+def test_cg_bills_pAp_where_it_is_reduced(one_chip, n, dtype):
+    """The one-chip cells' CG loop: ``p·Ap`` is billed to ``repro.matvec``
+    where XLA reduces it in the apply's last pass, and to ``repro.reduce``
+    where it keeps a pass of its own.  In float32 at 512³ it rides the
+    apply's pass, so ``reduce_ms`` no longer holds it."""
+    x64 = dtype == jnp.float64
+    jax.config.update("jax_enable_x64", x64)   # as the harness runs it
+    try:
+        b = _arg((n,) * 3, dtype, one_chip)
+        op = LocalOp(STENCIL_27PT)
+        hlo = jax.jit(lambda b, x0: SOLVERS["cg"](
+            op, b, x0, tol=1e-6, maxiter=600)).lower(b, b).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_x64", True)
+    found = _pAp_fusions(hlo)
+    assert len(found) == 1, found
+    for scope, slices in found:
+        assert scope == ("repro.matvec" if slices else "repro.reduce")
+    if not x64:
+        assert found[0][1] > 0, "p·Ap has a pass of its own"
+
+
 def test_f64_pallas_request_raises_before_compiling(one_chip, monkeypatch):
     from repro.api import SolverOptions, SolverSession
     # steer the session as it is steered on a TPU backend
@@ -163,6 +214,10 @@ def test_four_chip_cg_at_512_per_chip_compiles_for_v5e_2x2(v5e_2x2):
     hlo = compiled.as_text()
     assert "convolution" not in hlo
     assert "collective-permute" in hlo and "all-reduce" in hlo
+    # p·Ap's partials ride the passes of the interior's and the shell
+    # slabs' apply, billed with them; its psum is the all-reduce above
+    found = _pAp_fusions(hlo)
+    assert found and all(sc == "repro.matvec" and sl for sc, sl in found)
     mem = compiled.memory_analysis()
     # per chip: b and x0 (1.07 GB) in arguments; x, r, p, q and the
     # padded operand's pieces in temporaries
